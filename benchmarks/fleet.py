@@ -323,7 +323,7 @@ def design_space_sweep(n_devices: int = 64, bench: dict | None = None,
 
 
 #: Chunk size for the streamed (``reduce="stats"``) scaling runs: every
-#: lane count replays through identical ``SCALING_LANE_CHUNK``-lane donated
+#: lane count replays through identical ``SCALING_LANE_CHUNK``-lane
 #: buffers, so peak device-axis memory is a function of the chunk, never the
 #: fleet.  The budget is what one chunk's lane-side inputs + outputs cost
 #: (caps/rem0/tail + recharge & charge cumulative traces + per-lane result
@@ -858,7 +858,9 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny fleet; assert replay beats the scalar loop")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.smoke:
         # frontier_devices stays pinned at the full run's 256:
         # risk_ewma_recovery_max is a max over the cv axis and needs both

@@ -9,6 +9,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+import traceback
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
@@ -22,6 +23,7 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import fleet, kernels_bench, paper_figs, roofline
+    from repro.launch.compile_cache import enable_compile_cache
 
     sections = {
         "paper": paper_figs.run,
@@ -32,19 +34,25 @@ def main() -> None:
     wanted = sections if args.section == "all" else \
         {args.section: sections[args.section]}
 
+    enable_compile_cache()
     print("name,value,derived")
+    failed = []
     for name, fn in wanted.items():
         t0 = time.time()
         try:
             rows = fn()
-        except Exception as e:     # noqa: BLE001
+        except Exception as e:     # noqa: BLE001 -- report, run the rest
+            traceback.print_exc()
             print(f"{name}/ERROR,0,{type(e).__name__}: {e}")
+            failed.append(name)
             continue
         for row in rows:
             n, v, d = row
             print(f'{n},{v},"{d}"')
         print(f"# section {name} took {time.time()-t0:.1f}s",
               file=sys.stderr)
+    if failed:
+        raise SystemExit(f"benchmark sections failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

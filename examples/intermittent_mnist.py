@@ -224,8 +224,8 @@ def main():
     # (and the per-lane input traces behind them) stop fitting anywhere,
     # so ask the *question* instead of materializing the fleet.
     # reduce="stats" folds every lane into fixed-size running statistics
-    # inside the compiled replay and lane_chunk= streams the device axis
-    # through one constant-size donated buffer -- peak memory is set by
+    # chunk by chunk and lane_chunk= streams the device axis
+    # through one constant-size buffer -- peak memory is set by
     # the chunk, not the fleet, so the same call scales to 1e7 lanes
     # (the scaling curve lives in BENCH_fleet.json under fleet_scaling).
     big = 1_000_000

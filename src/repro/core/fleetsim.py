@@ -119,7 +119,7 @@ dynamic slice per event (``kernels/charge_replay.py``), so the entire
 (networks x tile-k x tiles x devices x capacitors) design space replays
 under ONE compiled scan -- no per-candidate re-extraction or recompile.
 Per-plan results come back as :class:`~repro.core.fleetstats.FleetStats`
-groups (``reduce="stats"``, mesh all-reduce and ``lane_chunk`` streaming
+groups (``reduce="stats"``, mesh sharding and ``lane_chunk`` streaming
 included) or as a materialized :class:`DesignSweepResult`, and each
 plan's lanes draw bit-identical sampler inputs to an individual
 ``fleet_sweep`` of that plan, so the stacked sweep is bit-exact against
@@ -133,11 +133,9 @@ Chunked streaming (``lane_chunk=``) runs as a two-stage pipeline by
 default (``prefetch=1``, :func:`_chunked_replay`): a bounded producer
 thread builds chunk k+1's inputs -- Philox ``*_stream`` draws,
 inert-lane padding, stochastic trace post-processing -- and uploads
-them to the device while chunk k's replay is in flight, and under
-``reduce="stats"`` each chunk's partial folds into a *device-resident*
-donated accumulator inside a tiny compiled merge
-(``fleetstats.merge_parts``), so the loop never syncs with the host
-until the final ``FleetStats`` materializes.  Host sampler time hides
+them to the device while chunk k's replay is in flight, and the host
+folds chunk k-1's outputs meanwhile (``reduce="stats"`` merges them
+into one :class:`FleetStats`).  Host sampler time hides
 under device compute instead of adding to it (the win scales with the
 host's spare cores; a 1-core runner sees ~1x).  The peak-memory bound
 is honest and recorded per sweep: ``peak_lane_bytes = (prefetch + 1) *
@@ -239,7 +237,7 @@ import numpy as np
 from .energy import (CLOCK_HZ, Device, JOULES_PER_CYCLE, LEA_COSTS,
                      OP_CLASSES, SOFTWARE_COSTS, class_cycle_vector,
                      make_power_system, rf_recharge_seconds)
-from .fleetstats import FleetStats, default_stat_edges
+from .fleetstats import FleetStats, default_stat_edges, stats_from_outputs
 from .inference import (Conv2D, DenseFC, SimNet, TAILS_FC_ENTRY_COSTS,
                         build_layer_segments, iter_task_spans,
                         naive_layer_cycles, run_naive, sonic_segments,
@@ -274,7 +272,8 @@ _TILE_FIELDS = ("tile_n", "tile_iter_cycles", "tile_iter_class",
 
 #: Replay backends: "auto" resolves to the fused XLA event stream for
 #: stochastic replays (the deterministic closed form ignores the knob),
-#: "pallas" opts into the Pallas lane kernel (interpret-mode on CPU), and
+#: "pallas" opts into the Pallas lane kernel (interpret mode only: on a
+#: TPU it raises, since Mosaic cannot lower its float64 state), and
 #: "_while" keeps the legacy data-dependent while-loop for differential
 #: testing (private; scheduled for removal once the fused path has been
 #: the default for one release).
@@ -282,9 +281,9 @@ REPLAY_BACKENDS = ("auto", "xla", "pallas", "_while")
 
 #: Output reductions: "none" materializes per-lane arrays (the bit-exact
 #: legacy path and the differential oracle), "stats" stream-reduces lanes
-#: into a fixed-size ``core.fleetstats.FleetStats`` inside the jit, so
-#: output (and, with ``lane_chunk=``, peak) memory is independent of the
-#: fleet size.
+#: into a fixed-size ``core.fleetstats.FleetStats`` chunk by chunk, so
+#: the result (and, with ``lane_chunk=``, peak) memory is independent of
+#: the fleet size.
 REPLAY_REDUCES = ("none", "stats")
 
 #: Default number of chunks the streamed replay's producer stage may run
@@ -1003,8 +1002,6 @@ def _jit_sharded_replay(mesh, shared_rows, adaptive: bool,
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import compat_shard_map
-
     fn = _vmap_replay(shared_rows, adaptive, parametric, stochastic,
                       backend, chunk, enable_fast, has_burn, has_send)
     lane = P("devices")
@@ -1013,128 +1010,10 @@ def _jit_sharded_replay(mesh, shared_rows, adaptive: bool,
                 P(), P(), P(), lane, P())
     if shared_rows == "plan":
         in_specs += (lane,)
-    return jax.jit(compat_shard_map(
-        fn, mesh, in_specs=in_specs, out_specs=lane))
-
-
-@lru_cache(maxsize=None)
-def _jit_replay_stats(shared_rows, adaptive: bool, parametric: bool,
-                      stochastic: bool, backend: str, chunk: int,
-                      enable_fast: bool, has_burn: bool, n_groups: int,
-                      donate: bool, has_send: bool = False):
-    """The replay with the fleet-statistics reduction fused into the same
-    jit: per-lane outputs are folded to ``(psums, pmins, pmaxs)`` partials
-    (``core.fleetstats``) before they ever leave the compiled call, and
-    ``donate=True`` additionally donates the per-lane input buffers so a
-    chunked sweep's peak memory is one chunk of lanes, not the fleet.
-    Under ``shared_rows="plan"`` the per-lane ``plan_idx`` operand rides
-    between ``alpha`` and the stats operands, and one statistics group per
-    candidate plan gives the design sweep its per-plan summaries."""
-    import jax
-
-    from .fleetstats import reduce_lane_outputs
-
-    fn = _vmap_replay(shared_rows, adaptive, parametric, stochastic,
-                      backend, chunk, enable_fast, has_burn, has_send)
-
-    # NB: `radio` is never donated -- the overlapped pipeline hoists one
-    # packed radio vector and reuses it across every chunk's call.
-    if shared_rows == "plan":
-        def run(rows, caps, rem0, tc, ts, ccum, nf, sr, theta, window,
-                alpha, conf, radio, pidx, gid, valid, edges):
-            out = fn(rows, caps, rem0, tc, ts, ccum, nf, sr, theta,
-                     window, alpha, conf, radio, pidx)
-            return reduce_lane_outputs(out, gid, valid, edges, n_groups)
-
-        dn = (1, 2, 3, 4, 5, 6, 7, 11, 13, 14, 15) if donate else ()
-        return jax.jit(run, donate_argnums=dn)
-
-    def run(rows, caps, rem0, tc, ts, ccum, nf, sr, theta, window, alpha,
-            conf, radio, gid, valid, edges):
-        out = fn(rows, caps, rem0, tc, ts, ccum, nf, sr, theta, window,
-                 alpha, conf, radio)
-        return reduce_lane_outputs(out, gid, valid, edges, n_groups)
-
-    dn = (1, 2, 3, 4, 5, 6, 7, 11, 13, 14) if donate else ()
-    return jax.jit(run, donate_argnums=dn)
-
-
-@lru_cache(maxsize=None)
-def _jit_sharded_replay_stats(mesh, shared_rows, adaptive: bool,
-                              parametric: bool, stochastic: bool,
-                              backend: str, chunk: int, enable_fast: bool,
-                              has_burn: bool, n_groups: int,
-                              has_send: bool = False):
-    """Sharded replay + in-shard stats reduction + cross-shard all-reduce:
-    each shard folds its lanes into partials and ``fleet_all_reduce``
-    (psum/pmin/pmax over the ``devices`` axis) leaves every shard holding
-    the identical fleet summary -- the only collective in the fleet path,
-    and the reason a sharded sweep's output size is independent of both
-    the fleet and the mesh."""
-    import jax
-    from jax.sharding import PartitionSpec as P
-
-    from repro.launch.mesh import compat_shard_map, fleet_all_reduce
-
-    from .fleetstats import reduce_lane_outputs
-
-    fn = _vmap_replay(shared_rows, adaptive, parametric, stochastic,
-                      backend, chunk, enable_fast, has_burn, has_send)
-
-    lane = P("devices")
-    rows_spec = lane if shared_rows is False else P()
-    if shared_rows == "plan":
-        def run(rows, caps, rem0, tc, ts, ccum, nf, sr, theta, window,
-                alpha, conf, radio, pidx, gid, valid, edges):
-            out = fn(rows, caps, rem0, tc, ts, ccum, nf, sr, theta,
-                     window, alpha, conf, radio, pidx)
-            parts = reduce_lane_outputs(out, gid, valid, edges, n_groups)
-            return fleet_all_reduce(parts, "devices")
-
-        in_specs = (rows_spec, lane, lane, lane, lane, lane, lane, lane,
-                    P(), P(), P(), lane, P(), lane, lane, lane, P())
-    else:
-        def run(rows, caps, rem0, tc, ts, ccum, nf, sr, theta, window,
-                alpha, conf, radio, gid, valid, edges):
-            out = fn(rows, caps, rem0, tc, ts, ccum, nf, sr, theta,
-                     window, alpha, conf, radio)
-            parts = reduce_lane_outputs(out, gid, valid, edges, n_groups)
-            return fleet_all_reduce(parts, "devices")
-
-        in_specs = (rows_spec, lane, lane, lane, lane, lane, lane, lane,
-                    P(), P(), P(), lane, P(), lane, lane, P())
-    return jax.jit(compat_shard_map(
-        run, mesh, in_specs=in_specs, out_specs=P()))
-
-
-@lru_cache(maxsize=None)
-def _jit_reduce_only(n_groups: int):
-    """Standalone jitted stats reduction over already-materialized lane
-    outputs (the Pallas backend's stats path, and a convenience for
-    validating the fused reduction)."""
-    import jax
-
-    from .fleetstats import reduce_lane_outputs
-
-    return jax.jit(lambda out, gid, valid, edges: reduce_lane_outputs(
-        out, gid, valid, edges, n_groups))
-
-
-@lru_cache(maxsize=None)
-def _jit_merge_parts(donate: bool):
-    """The device-resident stats accumulator: a tiny compiled call that
-    folds one chunk's ``(psums, pmins, pmaxs)`` partial into the running
-    partial (``fleetstats.merge_parts``) without ever leaving the device.
-    The running partial (argument 0) is donated where the platform
-    implements donation, so the accumulator is one buffer, not a history
-    of them.  A left fold of this call is bit-exact against the host-side
-    ``FleetStats.from_parts`` + ``merge`` loop (same f64 additions in the
-    same chunk order)."""
-    import jax
-
-    from .fleetstats import merge_parts
-
-    return jax.jit(merge_parts, donate_argnums=(0,) if donate else ())
+    # check_vma=False: lanes never communicate here, so there is no
+    # cross-shard value whose replication the type check could verify.
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=lane, check_vma=False))
 
 
 #: Measured event-chunk winners, keyed by (plan bucket shape x replay
@@ -1148,9 +1027,8 @@ def _autotune_event_chunk(key: tuple, s_bucket: int, dispatch) -> int:
     """Measured ``event_chunk="auto"`` resolution: time the candidate
     pow2 chunk lengths (``kernels.charge_replay.event_chunk_candidates``,
     the plan-shape default plus one octave either side) on the live
-    first-chunk operands via ``dispatch(candidate)`` -- which must run a
-    *non-donating* replay so the operands survive the timing runs -- and
-    cache the winner under ``key``.  Each candidate is dispatched twice
+    first-chunk operands via ``dispatch(candidate)`` and cache the
+    winner under ``key``.  Each candidate is dispatched twice
     (compile + warm) and the warm wall decides, so the tuner never picks
     a chunk on compile noise; the heuristic default is always among the
     candidates, bounding the worst case at "what the default already
@@ -1197,8 +1075,10 @@ def _validate_replay_knobs(policy: str, batch_rows: int,
 
 
 def _x64():
-    from jax.experimental import enable_x64
-    return enable_x64()
+    """Context manager for 64-bit mode (thread-local: the overlapped
+    pipeline's producer thread enters its own)."""
+    import jax
+    return jax.enable_x64(True)
 
 
 def _pad_axis0(a: np.ndarray, pad: int) -> np.ndarray:
@@ -1378,7 +1258,6 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
                 group_id: np.ndarray | None = None,
                 valid: np.ndarray | None = None,
                 edges: dict | None = None, n_groups: int = 1,
-                donate: bool = False,
                 plan_idx: np.ndarray | None = None,
                 conf: np.ndarray | None = None, radio=None,
                 config_out: dict | None = None) -> dict | tuple:
@@ -1479,16 +1358,15 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
         charge_cum = np.zeros((n_lanes, 1), np.float64)
     if tail_s is None:
         tail_s = np.zeros(n_lanes, np.float64)
+    tail_s = np.broadcast_to(np.asarray(tail_s, np.float64), (n_lanes,))
     if backend == "pallas" and mesh is not None:
         raise ValueError("backend='pallas' does not compose with mesh "
                          "sharding; use backend='xla' (or 'auto')")
     with _x64():
-        import jax
         import jax.numpy as jnp
         args = [{k: jnp.asarray(v) for k, v in rows.items()},
                 jnp.asarray(caps), jnp.asarray(rem0),
-                jnp.asarray(trace_cum), jnp.asarray(np.broadcast_to(
-                    np.asarray(tail_s, np.float64), (n_lanes,))),
+                jnp.asarray(trace_cum), jnp.asarray(tail_s),
                 jnp.asarray(charge_cum),
                 jnp.asarray(nominal_from),
                 jnp.asarray(s_real),
@@ -1500,109 +1378,119 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
                 jnp.asarray(radio_vec)]
         if plan_mode:
             args.append(jnp.asarray(np.asarray(plan_idx, np.int32)))
-        stats = reduce == "stats"
-        if stats:
-            gid = jnp.asarray(
-                np.zeros(n_lanes, np.int32) if group_id is None
-                else np.asarray(group_id, np.int32))
-            vld = jnp.asarray(
-                np.ones(n_lanes, bool) if valid is None
-                else np.asarray(valid, bool))
-            jedges = {k: jnp.asarray(e) for k, e in edges.items()}
-            # Donation only where the platform implements it; elsewhere it
-            # just warns and copies.
-            donate = donate and jax.default_backend() != "cpu"
         if autotune:
-            def _time_candidate(c):
-                if stats:
-                    return _jit_replay_stats(
-                        shared_rows, adaptive, parametric, stochastic,
-                        backend, c, enable_fast, has_burn, n_groups,
-                        False, has_send)(*args, gid, vld, jedges)
-                return _jit_replay(shared_rows, adaptive, parametric,
-                                   stochastic, backend, c, enable_fast,
-                                   has_burn, has_send)(*args)
-
             chunk = _autotune_event_chunk(
                 (shared_rows, adaptive, parametric, stochastic, backend,
                  enable_fast, has_burn, has_send, rows["kind"].shape,
-                 n_lanes, n_groups if stats else None),
-                rows["kind"].shape[s_axis],
-                _time_candidate)
+                 n_lanes), rows["kind"].shape[s_axis],
+                lambda c: _jit_replay(shared_rows, adaptive, parametric,
+                                      stochastic, backend, c, enable_fast,
+                                      has_burn, has_send)(*args))
             if config_out is not None:
                 config_out["chunk"] = chunk
+        xla_backend = "xla" if backend == "pallas" else backend
         if backend == "pallas" and stochastic:
-            # The Pallas lane kernel (interpret-mode on CPU); the
+            # The Pallas lane kernel (interpret mode only); the
             # deterministic closed form has no charge loop to fuse, so a
             # non-stochastic replay under backend="pallas" falls through
             # to the XLA path below.
             from repro.kernels.ops import charge_replay as _pallas_replay
-            out = _pallas_replay(*args, adaptive=adaptive,
+            res = _pallas_replay(*args, adaptive=adaptive,
                                  parametric=parametric,
                                  shared_rows=shared_rows,
                                  enable_fast=enable_fast,
                                  has_burn=has_burn, has_send=has_send,
                                  chunk=chunk)
-            if stats:
-                parts = _jit_reduce_only(n_groups)(out, gid, vld, jedges)
-                return jax.tree_util.tree_map(np.asarray, parts)
-            return {k: np.asarray(v) for k, v in out.items()}
-        xla_backend = "xla" if backend == "pallas" else backend
-        if mesh is None:
-            if stats:
-                parts = _jit_replay_stats(
-                    shared_rows, adaptive, parametric, stochastic,
-                    xla_backend, chunk, enable_fast, has_burn, n_groups,
-                    donate, has_send)(*args, gid, vld, jedges)
-                return jax.tree_util.tree_map(np.asarray, parts)
-            out = _jit_replay(shared_rows, adaptive, parametric,
+        elif mesh is None:
+            res = _jit_replay(shared_rows, adaptive, parametric,
                               stochastic, xla_backend, chunk,
                               enable_fast, has_burn, has_send)(*args)
-            return {k: np.asarray(v) for k, v in out.items()}
-        # shard_map: pad the lane axis to a mesh multiple with inert
-        # continuous lanes (cap = rem0 = inf completes every row in one
-        # pass), then strip the padding from the outputs.
-        n_shards = int(mesh.devices.size)
-        pad = (-n_lanes) % n_shards
-        if pad:
-            # caps, rem0, trace, tail, charge_cum, nominal_from, s_real
-            # lane fills (s_real=0: the fused event stream skips the pad
-            # lanes outright)
-            fills = (np.inf, np.inf, 0.0, 0.0, 0.0, 0.0, 0)
-            for i, fill in enumerate(fills, start=1):
-                args[i] = jnp.concatenate(
-                    [args[i], jnp.full((pad,) + args[i].shape[1:], fill,
-                                       args[i].dtype)], axis=0)
-            # conf pads with zeros (s_real=0 lanes never take a decision)
-            args[11] = jnp.concatenate(
-                [args[11], jnp.zeros(pad, args[11].dtype)])
-            if plan_mode:
-                # pad lanes point at candidate 0; s_real=0 skips them
-                args[13] = jnp.concatenate(
-                    [args[13], jnp.zeros(pad, args[13].dtype)])
-            if shared_rows is False:
-                args[0] = {k: jnp.concatenate(
-                    [v, jnp.zeros((pad,) + v.shape[1:], v.dtype)], axis=0)
-                    for k, v in args[0].items()}
-        if stats:
-            if pad:
-                # padding lanes are masked out of every statistic
-                gid = jnp.concatenate([gid, jnp.zeros(pad, gid.dtype)])
-                vld = jnp.concatenate([vld, jnp.zeros(pad, bool)])
-            parts = _jit_sharded_replay_stats(
-                mesh, shared_rows, adaptive, parametric, stochastic,
-                xla_backend, chunk, enable_fast, has_burn,
-                n_groups, has_send)(*args, gid, vld, jedges)
-            return jax.tree_util.tree_map(np.asarray, parts)
-        out = _jit_sharded_replay(mesh, shared_rows, adaptive, parametric,
+        else:
+            res = _sharded_replay(args, mesh, n_lanes, plan_mode,
+                                  shared_rows, adaptive, parametric,
                                   stochastic, xla_backend, chunk,
-                                  enable_fast, has_burn, has_send)(*args)
-        return {k: np.asarray(v)[:n_lanes] for k, v in out.items()}
+                                  enable_fast, has_burn, has_send)
+    out = _lane_results(res, n_lanes, trace_cum, tail_s, has_send)
+    if reduce == "stats":
+        keep = (np.ones(n_lanes, bool) if valid is None
+                else np.asarray(valid, bool))
+        gid = (np.zeros(n_lanes, np.int64) if group_id is None
+               else np.asarray(group_id))
+        return stats_from_outputs({k: v[keep] for k, v in out.items()},
+                                  edges, gid[keep], n_groups)
+    return out
+
+
+def _sharded_replay(args: list, mesh, n_lanes: int, plan_mode: bool,
+                    shared_rows, *key):
+    """The replay under ``shard_map``: pad the lane axis to a mesh
+    multiple with inert continuous lanes (cap = rem0 = inf completes every
+    row in one pass); the caller strips the padding from the outputs."""
+    import jax.numpy as jnp
+
+    n_shards = int(mesh.devices.size)
+    pad = (-n_lanes) % n_shards
+    if pad:
+        # caps, rem0, trace, tail, charge_cum, nominal_from, s_real
+        # lane fills (s_real=0: the fused event stream skips the pad
+        # lanes outright)
+        fills = (np.inf, np.inf, 0.0, 0.0, 0.0, 0.0, 0)
+        for i, fill in enumerate(fills, start=1):
+            args[i] = jnp.concatenate(
+                [args[i], jnp.full((pad,) + args[i].shape[1:], fill,
+                                   args[i].dtype)], axis=0)
+        # conf pads with zeros (s_real=0 lanes never take a decision)
+        args[11] = jnp.concatenate(
+            [args[11], jnp.zeros(pad, args[11].dtype)])
+        if plan_mode:
+            # pad lanes point at candidate 0; s_real=0 skips them
+            args[13] = jnp.concatenate(
+                [args[13], jnp.zeros(pad, args[13].dtype)])
+        if shared_rows is False:
+            args[0] = {k: jnp.concatenate(
+                [v, jnp.zeros((pad,) + v.shape[1:], v.dtype)], axis=0)
+                for k, v in args[0].items()}
+    return _jit_sharded_replay(mesh, shared_rows, *key)(*args)
+
+
+def _dead_time(reboots, trace_cum, tail_s) -> np.ndarray:
+    """Each lane's dead time from its final reboot count: the recharge
+    trace's window over reboots ``[0, R)`` plus the mean recharge for
+    every reboot past the trace's end (``trace_cum`` ``(L, T + 1)``, or
+    ``None`` for the mean alone).  This is what the replay's per-row
+    windows add up to when no SEND row waits for a window; computed
+    here, on the host, it is IEEE float64 on every platform (a TPU's
+    emulated float64 is not) and bit-exact against the reference
+    interpreter's ``trace_window(cum, 0, R, tail)``."""
+    r = np.asarray(reboots, np.float64)
+    tail = np.broadcast_to(np.asarray(tail_s, np.float64), r.shape)
+    if trace_cum is None:
+        return r * tail
+    cum = np.asarray(trace_cum, np.float64)
+    last = cum.shape[1] - 1
+    i1 = np.minimum(np.maximum(r, 0.0), last).astype(np.int64)
+    return (cum[np.arange(r.shape[0]), i1] - cum[:, 0]
+            + np.maximum(r - last, 0.0) * tail)
+
+
+def _lane_results(res: dict, m: int, trace_cum, tail_s,
+                  has_send: bool) -> dict:
+    """A replay call's per-lane outputs as host arrays, cut to the first
+    ``m`` (real) lanes.  Every channel the device returns is a whole
+    number of cycles or events, exact in the device's float64; dead time
+    in seconds is not, so without SEND rows it is recomputed here
+    (:func:`_dead_time`).  With SEND rows the replay's own dead time
+    stands: window waits depend on it mid-replay."""
+    out = {k: np.asarray(v)[:m] for k, v in res.items()}
+    if not has_send:
+        out["dead"] = _dead_time(out["reboots"], trace_cum[:m],
+                                 tail_s[:m])
+    return out
 
 
 def _lane_io_bytes(n_lanes: int, *arrays) -> int:
     """Host-visible per-lane buffer bytes of one replay call: the per-lane
-    input arrays plus the in-jit per-lane output channels (9 f64 scalars
+    input arrays plus the per-lane output channels (9 f64 scalars
     -- including the three uplink channels -- the per-class cycle matrix,
     and the bool ``stuck`` flag).  This is the quantity the memory-flat
     bench asserts is a function of the chunk size, not the fleet size."""
@@ -1624,7 +1512,7 @@ def _chunked_replay(plan_rows: dict, n_rows, n_lanes: int,
     m)`` (chunk-invariant counter-based samplers, so the chunking never
     changes a lane's inputs), the final partial chunk is padded to
     ``lane_chunk`` with inert masked lanes so every chunk reuses one
-    compiled program, and lane buffers are donated to the jit.  Under
+    compiled program.  Under
     ``reduce="stats"`` chunk partials merge associatively into one
     :class:`FleetStats` -- peak lane memory is the chunk, not the fleet.
     Under ``reduce="none"`` per-chunk outputs are concatenated
@@ -1644,18 +1532,15 @@ def _chunked_replay(plan_rows: dict, n_rows, n_lanes: int,
     trace post-processing (column pow2-padding + ``nominal_from``) and
     non-blocking device upload run while chunk k's replay is in flight,
     with a token semaphore bounding the pipeline to ``prefetch + 1``
-    chunks alive at once.  Stage 2 (device-resident accumulation, under
-    ``reduce="stats"``): each chunk's partial folds into a donated
-    running partial inside a tiny compiled merge
-    (``fleetstats.merge_parts``), so the loop never syncs per chunk --
-    the single host sync is the final ``FleetStats.from_parts``.  Chunk
-    partials fold left in chunk order, bitwise the additions the
-    sequential loop's host merge performs, so ``prefetch=0`` (exactly
-    the legacy loop) is the bit-compat differential oracle for the
-    pipeline; ``peak_lane_bytes`` reports the honest pipeline bound:
-    ``(prefetch + 1)`` chunk buffers plus one stats partial.  The mesh
-    and Pallas paths keep their own dispatch (``_run_replay``) and
-    overlap stage 1 only."""
+    chunks alive at once.  Stage 2 (host): chunk k-1's outputs come back
+    and fold into the running :class:`FleetStats` while chunk k replays,
+    so the device always has the next chunk queued.  Chunk partials fold
+    left in chunk order, the additions the sequential loop performs, so
+    ``prefetch=0`` (exactly the legacy loop) is the bit-compat
+    differential oracle for the pipeline; ``peak_lane_bytes`` reports
+    the honest pipeline bound: ``(prefetch + 1)`` chunk buffers plus one
+    stats partial.  The mesh and Pallas paths keep their own dispatch
+    (``_run_replay``) and overlap stage 1 only."""
     if lane_chunk < 1:
         raise ValueError(f"lane_chunk must be >= 1, got {lane_chunk}")
     if prefetch < 0:
@@ -1739,7 +1624,7 @@ def _chunked_replay(plan_rows: dict, n_rows, n_lanes: int,
             n_rows=c["nr"] if (plan_mode or per_lane_rows) else n_rows,
             chunk=event_chunk, reduce=reduce, group_id=c["gid"],
             valid=c["valid"], edges=edges, n_groups=n_groups,
-            donate=True, plan_idx=c["pidx"], conf=c["conf"], radio=radio,
+            plan_idx=c["pidx"], conf=c["conf"], radio=radio,
             config_out=config_out)
 
     if prefetch == 0 or len(starts) == 1:
@@ -1754,9 +1639,8 @@ def _chunked_replay(plan_rows: dict, n_rows, n_lanes: int,
             peak = max(peak, chunk_bytes(c))
             res = run_chunk(c)
             if stats:
-                part = FleetStats.from_parts(res, edges)
-                acc_stats = part if acc_stats is None \
-                    else acc_stats.merge(part)
+                acc_stats = res if acc_stats is None \
+                    else acc_stats.merge(res)
             else:
                 outs.append({k: v[:c["m"]] for k, v in res.items()})
         if stats:
@@ -1781,10 +1665,9 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
                        radio=None):
     """The ``prefetch >= 1`` body of :func:`_chunked_replay`: a bounded
     producer thread runs chunk generation + device upload ahead of the
-    replay, and (on the unmeshed XLA path) a donated device-resident
-    partial accumulates chunk statistics without per-chunk host syncs.
-    See :func:`_chunked_replay` for the contract; results are bit-exact
-    against ``prefetch=0``."""
+    replay, and (on the unmeshed XLA path) the host folds each chunk
+    while the next one replays.  See :func:`_chunked_replay` for the
+    contract; results are bit-exact against ``prefetch=0``."""
     import queue as queue_mod
     import threading
 
@@ -1810,9 +1693,8 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
 
     first = build(starts[0])
     prep = lambda c: c                      # noqa: E731 -- fallback path
-    dispatch = acc_merge = None
+    dispatch = None
     if fast:
-        import jax
         import jax.numpy as jnp
 
         from repro.runtime.radio import N_RADIO, radio_vector
@@ -1823,8 +1705,7 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
                       or (adaptive and batch_rows > 1))
         xla_backend = "xla" if backend == "auto" else backend
         # Uplink operands are chunk-invariant: the packed radio vector is
-        # hoisted and reused across every chunk's call (it is never
-        # donated -- see _jit_replay_stats).
+        # hoisted and reused across every chunk's call.
         has_send = (radio is not None
                     and bool(np.any(np.asarray(plan_rows["kind"])
                                     == KIND_SEND)))
@@ -1857,9 +1738,6 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
             jwindow = jnp.asarray(float(batch_rows), jnp.float64)
             jalpha = jnp.asarray(float(belief_alpha), jnp.float64)
             jradio = jnp.asarray(radio_vec)
-            jedges = ({k: jnp.asarray(e) for k, e in edges.items()}
-                      if stats else None)
-        donate = jax.default_backend() != "cpu"
 
         def prep(c):  # noqa: F811
             """Pipeline stage 1b (producer thread): stochastic trace
@@ -1907,22 +1785,13 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
                 if plan_mode:
                     args.append(jnp.asarray(
                         np.asarray(c["pidx"], np.int32)))
-                extra = ((jnp.asarray(c["gid"]),
-                          jnp.asarray(c["valid"])) if stats else ())
-            return c, enable_fast, args, extra
+            return c, enable_fast, args, cum, tail
 
-        def dispatch(item, dn, ec):  # noqa: F811
-            _, enable_fast, args, extra = item
-            if stats:
-                return _jit_replay_stats(
-                    shared_rows, adaptive, parametric, stochastic,
-                    xla_backend, ec, enable_fast, has_burn, n_groups,
-                    dn, has_send)(*args, *extra, jedges)
+        def dispatch(item, ec):  # noqa: F811
+            _, enable_fast, args, _, _ = item
             return _jit_replay(shared_rows, adaptive, parametric,
                                stochastic, xla_backend, ec, enable_fast,
                                has_burn, has_send)(*args)
-
-        acc_merge = _jit_merge_parts(donate)
 
     tokens.acquire()                        # the first chunk's slot
     item0 = prep(first)
@@ -1931,9 +1800,8 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
             echunk = _autotune_event_chunk(
                 (shared_rows, adaptive, parametric, stochastic,
                  xla_backend, item0[1], has_burn, has_send,
-                 item0[2][0]["kind"].shape, lane_chunk,
-                 n_groups if stats else None), s_bucket,
-                lambda c: dispatch(item0, False, c))
+                 item0[2][0]["kind"].shape, lane_chunk), s_bucket,
+                lambda c: dispatch(item0, c))
     if fast and config_out is not None:
         config_out.update(
             shared_rows=shared_rows, adaptive=adaptive,
@@ -1960,45 +1828,45 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
     acc = None
     outs: list[dict] = []
     peak_chunk = 0
-    pending: list = []                      # unsynced partial handles
+
+    def keep(c, res):
+        """Fold one chunk's results: stats merge left in chunk order."""
+        nonlocal acc
+        if stats:
+            acc = res if acc is None else acc.merge(res)
+        else:
+            outs.append({k: v[:c["m"]] for k, v in res.items()})
+
+    def finish(item, res):
+        c, _, _, cum, tail = item
+        out = _lane_results(res, c["m"], cum, tail, has_send)
+        keep(c, stats_from_outputs(out, edges, c["gid"][:c["m"]], n_groups)
+             if stats else out)
+
     try:
         if fast:
             with _x64():
-                import jax
+                held = None                 # (item, result) still replaying
                 for i in range(len(starts)):
                     item = item0 if i == 0 else q.get()
                     if isinstance(item, BaseException):
                         raise item
-                    c = item[0]
-                    peak_chunk = max(peak_chunk, chunk_bytes(c))
-                    res = dispatch(item, donate, echunk)
-                    if stats:
-                        acc = res if acc is None else acc_merge(acc, res)
-                        pending.append(acc)
-                        if len(pending) > prefetch:
-                            # backpressure: the (i - prefetch)-th partial
-                            # being ready means that chunk's replay has
-                            # retired -- release its pipeline slot
-                            jax.block_until_ready(pending.pop(0))
-                            tokens.release()
-                    else:
-                        outs.append({k: np.asarray(v)[:c["m"]]
-                                     for k, v in res.items()})
+                    peak_chunk = max(peak_chunk, chunk_bytes(item[0]))
+                    res = dispatch(item, echunk)
+                    if held is not None:
+                        # fold the previous chunk while this one replays
+                        finish(*held)
                         tokens.release()
+                    held = (item, res)
+                finish(*held)
+                tokens.release()
         else:
-            acc_stats = None
             for i in range(len(starts)):
                 c = item0 if i == 0 else q.get()
                 if isinstance(c, BaseException):
                     raise c
                 peak_chunk = max(peak_chunk, chunk_bytes(c))
-                res = run_chunk(c)
-                if stats:
-                    part = FleetStats.from_parts(res, edges)
-                    acc_stats = part if acc_stats is None \
-                        else acc_stats.merge(part)
-                else:
-                    outs.append({k: v[:c["m"]] for k, v in res.items()})
+                keep(c, run_chunk(c))
                 tokens.release()
     except BaseException:
         fail.set()
@@ -2009,9 +1877,8 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
     peak = (peak_chunk * min(depth, len(starts))
             + (partial_nbytes(edges, n_groups) if stats else 0))
     if stats:
-        st = (FleetStats.from_parts(acc, edges) if fast else acc_stats)
-        st.peak_lane_bytes = peak
-        return st
+        acc.peak_lane_bytes = peak
+        return acc
     return {k: np.concatenate([o[k] for o in outs])
             for k in outs[0]}, peak
 
@@ -2080,7 +1947,7 @@ def replay_plans(plans: list[FleetPlan],
     ``max_atomic`` check for everything else.
 
     ``reduce="stats"`` folds the lanes into one :class:`FleetStats`
-    inside the jit (``REPLAY_REDUCES``) instead of materializing
+    (``REPLAY_REDUCES``) instead of materializing
     :class:`ReplayOut` rows; ``stats_bins``/``stats_edges`` size its
     fixed histogram bins (defaults derived from the plans' nominal
     bounds).
@@ -2203,7 +2070,7 @@ def replay_plans(plans: list[FleetPlan],
             return res
         out, _peak = res
     elif reduce == "stats":
-        parts = _run_replay(_pad_stack(plans), caps, rem0,
+        stats = _run_replay(_pad_stack(plans), caps, rem0,
                             shared_rows=False, trace_cum=cum, tail_s=tail,
                             policy=policy, theta=theta,
                             batch_rows=batch_rows,
@@ -2211,7 +2078,6 @@ def replay_plans(plans: list[FleetPlan],
                             backend=backend, n_rows=n_rows_arr,
                             chunk=event_chunk, reduce="stats",
                             edges=edges, conf=conf, radio=radio)
-        stats = FleetStats.from_parts(parts, edges)
         stats.wall_s = time.perf_counter() - t0
         stats.peak_lane_bytes = _lane_io_bytes(len(plans), caps, rem0,
                                                tail, cum, ccum)
@@ -2327,6 +2193,10 @@ class FleetSweepResult:
     msgs_sent: np.ndarray | None = None      # (D,)
     msgs_deferred: np.ndarray | None = None  # (D,) closed-window defers
     tx_joules: np.ndarray | None = None      # (D,) radio energy burned
+    #: The replay's raw per-lane channels (exact live cycles, the per-class
+    #: cycle matrix, ...): what ``stats_from_outputs`` and the reference
+    #: interpreter are compared against.
+    outputs: dict | None = None
 
     @property
     def total_s(self) -> np.ndarray:
@@ -2572,11 +2442,10 @@ def _design_sweep(ps: PlanSet, n_devices: int, seed: int,
                   plan_idx=pidx, config_out=config_out,
                   conf=conf, radio=radio)
     if reduce == "stats":
-        parts = _run_replay(ps.rows, caps, rem0, "plan", reduce="stats",
+        stats = _run_replay(ps.rows, caps, rem0, "plan", reduce="stats",
                             group_id=pidx, edges=edges, n_groups=n_plans,
                             **common)
-        stats = FleetStats.from_parts(parts, edges,
-                                      group_labels=np.asarray(ps.labels))
+        stats.group_labels = np.asarray(ps.labels)
         stats.wall_s = time.perf_counter() - t0
         stats.peak_lane_bytes = _lane_io_bytes(lanes, caps, rem0, tail,
                                                cum, ccum, pidx)
@@ -2629,9 +2498,9 @@ def fleet_sweep(net: SimNet | None = None, x: np.ndarray | None = None,
     product.
 
     ``reduce="stats"`` replaces the per-lane result arrays with one
-    fixed-size :class:`FleetStats` folded inside the jit
-    (``REPLAY_REDUCES``), and ``lane_chunk=`` additionally streams the
-    device axis through that many lanes at a time -- per-chunk inputs
+    fixed-size :class:`FleetStats` (``REPLAY_REDUCES``), and
+    ``lane_chunk=`` additionally streams the device axis through that
+    many lanes at a time -- per-chunk inputs
     come from the chunk-invariant ``*_stream`` samplers in
     ``runtime.failures`` (so results do not depend on the chunking, but
     differ bitwise from the legacy unchunked draw stream), chunk partials
@@ -2763,7 +2632,8 @@ def fleet_sweep(net: SimNet | None = None, x: np.ndarray | None = None,
             msgs_sent=out.get("msgs_sent"),
             msgs_deferred=out.get("msgs_deferred"),
             tx_joules=out["classes"][..., _RADIO_IDX] * JOULES_PER_CYCLE
-            if "classes" in out else None)
+            if "classes" in out else None,
+            outputs=out)
     frac = initial_charge_fraction(n_devices, seed=seed)
     jit_mult = harvest_jitter(n_devices, seed=seed + 1, cv=recharge_cv)
     caps = np.full(n_devices, plan.capacity, np.float64)
@@ -2785,7 +2655,7 @@ def fleet_sweep(net: SimNet | None = None, x: np.ndarray | None = None,
         # Unchunked stats: same legacy input draws as reduce="none", so
         # the reduction is bit-exactly comparable to statistics computed
         # from the materialized outputs (the differential oracle).
-        parts = _run_replay(_plan_rows(plan), caps, rem0,
+        stats = _run_replay(_plan_rows(plan), caps, rem0,
                             shared_rows=True, trace_cum=cum, tail_s=tail,
                             policy=policy, theta=theta,
                             batch_rows=batch_rows,
@@ -2793,7 +2663,6 @@ def fleet_sweep(net: SimNet | None = None, x: np.ndarray | None = None,
                             mesh=mesh, backend=backend, n_rows=len(plan),
                             chunk=event_chunk, reduce="stats",
                             edges=edges, conf=conf, radio=radio)
-        stats = FleetStats.from_parts(parts, edges)
         stats.wall_s = time.perf_counter() - t0
         stats.peak_lane_bytes = _lane_io_bytes(n_devices, caps, rem0,
                                                tail, cum, ccum)
@@ -2820,7 +2689,8 @@ def fleet_sweep(net: SimNet | None = None, x: np.ndarray | None = None,
         msgs_sent=out.get("msgs_sent"),
         msgs_deferred=out.get("msgs_deferred"),
         tx_joules=out["classes"][..., _RADIO_IDX] * JOULES_PER_CYCLE
-        if "classes" in out else None)
+        if "classes" in out else None,
+        outputs=out)
 
 
 @dataclass
@@ -2875,7 +2745,7 @@ def capacitor_sweep(net: SimNet, x: np.ndarray,
 
     ``reduce="stats"`` folds the grid into one :class:`FleetStats` with
     one statistics *group per capacitor* (``group_labels`` holds the
-    capacities) inside the jit, and ``lane_chunk=`` streams the flat
+    capacities), and ``lane_chunk=`` streams the flat
     (capacitor-major) lane axis through that many lanes at a time with
     chunk-invariant samplers -- see :func:`fleet_sweep` for the
     memory-flat semantics.
@@ -2964,7 +2834,7 @@ def capacitor_sweep(net: SimNet, x: np.ndarray,
         ccum = charge_trace_cumulative(ctr)
     if reduce == "stats":
         gid = np.repeat(np.arange(n_caps, dtype=np.int32), n_devices)
-        parts = _run_replay(_plan_rows(plan), caps, rem0,
+        stats = _run_replay(_plan_rows(plan), caps, rem0,
                             shared_rows=True, tail_s=tail, policy=policy,
                             theta=theta, batch_rows=batch_rows,
                             belief_alpha=belief_alpha, charge_cum=ccum,
@@ -2972,8 +2842,7 @@ def capacitor_sweep(net: SimNet, x: np.ndarray,
                             chunk=event_chunk, reduce="stats",
                             group_id=gid, edges=edges,
                             n_groups=n_caps)
-        stats = FleetStats.from_parts(parts, edges,
-                                      group_labels=capacities)
+        stats.group_labels = np.asarray(capacities)
         stats.wall_s = time.perf_counter() - t0
         stats.peak_lane_bytes = _lane_io_bytes(lanes, caps, rem0, tail,
                                                ccum)

@@ -6,14 +6,17 @@ full per-lane ``ReplayOut`` rows -- at 1e7 devices the *outputs* alone are
 gigabytes and the per-lane input traces are tens of gigabytes, so the
 device axis died of memory long before the "millions of users" scale the
 ROADMAP asks for.  This module is the reduction layer that replaces those
-rows: a replay chunk's per-lane outputs are folded **inside the same jit**
-into a fixed-size :class:`FleetStats` partial (running counts, sums, sums
-of squares, min/max, and fixed-bin histograms per output channel), and
-partials accumulate **associatively** -- across lane chunks on the host
-(``lane_chunk=`` in ``fleet_sweep``/``capacitor_sweep``) and across
-``shard_map`` shards on the mesh (``repro.launch.mesh.fleet_all_reduce``)
--- so peak memory is a function of the chunk size and the histogram shape,
-never the fleet size.
+rows: as each replay chunk's per-lane outputs reach the host they are
+folded into a fixed-size :class:`FleetStats` partial (running counts,
+sums, sums of squares, min/max, and fixed-bin histograms per output
+channel), and partials accumulate **associatively** across lane chunks
+(``lane_chunk=`` in ``fleet_sweep``/``capacitor_sweep``), so peak memory
+is a function of the chunk size and the histogram shape, never the fleet
+size.  The fold runs on the host, in IEEE float64, because a TPU's
+float64 is emulated and not IEEE: there the real-valued sums would not
+be reproducible.  The device returns whole numbers of cycles and events,
+which its float64 holds exactly, and whatever order a mesh or a chunking
+visits the lanes in, the same numpy fold sees them in lane order.
 
 The statistics answer exactly the offline, aggregate cost queries that
 hardware-aware search over device fleets needs (per-layer latency-table
@@ -48,8 +51,7 @@ record the true extremes so a clipped tail is visible.
 ``default_stat_edges`` derives serviceable linear edges from the plan's
 nominal bounds.
 
-Like the rest of ``repro.core``, importing this module never imports JAX;
-the in-jit reduction (:func:`reduce_lane_outputs`) defers its imports.
+Like the rest of ``repro.core``, importing this module never imports JAX.
 """
 
 from __future__ import annotations
@@ -118,8 +120,7 @@ def default_stat_edges(total_cycles: float, capacity: float,
 
 
 def lane_channels(out: dict) -> dict:
-    """The per-lane ``STAT_CHANNELS`` values of a replay output dict
-    (works on numpy arrays and on traced jnp arrays alike).  Output
+    """The per-lane ``STAT_CHANNELS`` values of a replay output dict.  Output
     dicts predating the uplink channels (hand-built oracles) fold in as
     all-zero; ``tx_joules`` is derived from the per-class cycle
     breakdown rather than carried as a separate scan output."""
@@ -140,91 +141,11 @@ def lane_channels(out: dict) -> dict:
     }
 
 
-def reduce_lane_outputs(out: dict, group_id, valid, edges: dict,
-                        n_groups: int) -> tuple:
-    """Fold a replay chunk's per-lane outputs into per-group stats
-    partials, inside the jit that produced them (the per-lane arrays
-    never have to leave the device or survive the call).
-
-    ``group_id`` assigns each lane to a statistics group (``(L,)`` int32;
-    all-zero for ``fleet_sweep``, the capacitor index for
-    ``capacitor_sweep``), ``valid`` masks chunk-padding lanes out of
-    every statistic, and ``edges`` maps each ``STAT_CHANNELS`` entry to
-    its fixed ``(bins + 1,)`` bin edges.
-
-    Returns ``(psums, pmins, pmaxs)`` pytrees split by their cross-shard
-    reduction operator, so a ``shard_map`` caller can all-reduce them
-    with ``repro.launch.mesh.fleet_all_reduce`` and every shard ends up
-    holding the identical fleet summary.
-    """
-    import jax.numpy as jnp
-
-    valid = jnp.asarray(valid)
-    gid = jnp.asarray(group_id, jnp.int32)
-    done = (~out["stuck"]) & valid
-    w = done.astype(jnp.float64)            # distribution mask
-    vals = lane_channels(out)
-
-    def gsum(v):
-        return jnp.zeros((n_groups,), jnp.float64).at[gid].add(v)
-
-    psums = {
-        "count": gsum(valid.astype(jnp.float64)),
-        "completed": gsum(w),
-        "class_sums": jnp.zeros((n_groups, _N_CLASSES), jnp.float64)
-        .at[gid].add(out["classes"] * w[:, None]),
-    }
-    pmins, pmaxs = {}, {}
-    for ch in STAT_CHANNELS:
-        v = vals[ch]
-        e = jnp.asarray(edges[ch])
-        bins = e.shape[0] - 1
-        # masked-out lanes are pushed to +/-inf so scatter-min/max ignore
-        # them; histogram indices clip into the end bins.
-        idx = jnp.clip(jnp.searchsorted(e, v, side="right") - 1,
-                       0, bins - 1)
-        psums[f"{ch}:sum"] = gsum(v * w)
-        psums[f"{ch}:sumsq"] = gsum(v * v * w)
-        psums[f"{ch}:hist"] = (
-            jnp.zeros((n_groups, bins), jnp.float64)
-            .at[gid, idx].add(w))
-        pmins[ch] = (jnp.full((n_groups,), jnp.inf, jnp.float64)
-                     .at[gid].min(jnp.where(done, v, jnp.inf)))
-        pmaxs[ch] = (jnp.full((n_groups,), -jnp.inf, jnp.float64)
-                     .at[gid].max(jnp.where(done, v, -jnp.inf)))
-    return psums, pmins, pmaxs
-
-
-def merge_parts(a: tuple, b: tuple) -> tuple:
-    """In-jit associative merge of two ``(psums, pmins, pmaxs)`` partials
-    (the return shape of :func:`reduce_lane_outputs`): sums add, mins
-    take the elementwise minimum, maxs the maximum.
-
-    This is the device-resident accumulation step of the overlapped
-    chunk pipeline (``fleetsim._chunked_replay`` with ``prefetch >= 1``):
-    instead of round-tripping every chunk's partial through
-    :meth:`FleetStats.from_parts` + host :meth:`FleetStats.merge`, the
-    running partial stays a (donated) device buffer and folds each new
-    chunk inside a tiny compiled call, so the stream never syncs to the
-    host until the final chunk.  A left fold of ``merge_parts`` performs
-    bitwise the same f64 additions in the same order as the host merge
-    loop, so the two accumulation paths are bit-exact (pinned by
-    ``tests/test_pipeline.py``).  Works on traced jnp arrays and numpy
-    arrays alike."""
-    import jax
-    import jax.numpy as jnp
-
-    (psa, pna, pxa), (psb, pnb, pxb) = a, b
-    return (jax.tree_util.tree_map(jnp.add, psa, psb),
-            jax.tree_util.tree_map(jnp.minimum, pna, pnb),
-            jax.tree_util.tree_map(jnp.maximum, pxa, pxb))
-
-
 def partial_nbytes(edges: dict, n_groups: int) -> int:
-    """Size in bytes of one ``(psums, pmins, pmaxs)`` stats partial for
-    ``n_groups`` groups under ``edges`` -- the device-resident
-    accumulator's contribution to the streamed pipeline's peak-memory
-    bound (2 chunk buffers + 1 stats buffer)."""
+    """Size in bytes of one :class:`FleetStats` partial for ``n_groups``
+    groups under ``edges`` -- the running accumulator's contribution to
+    the streamed pipeline's peak-memory bound (2 chunk buffers + 1 stats
+    buffer)."""
     per_group = 2 + _N_CLASSES          # count, completed, class_sums
     for ch in STAT_CHANNELS:
         bins = np.asarray(edges[ch]).shape[0] - 1
@@ -250,26 +171,6 @@ class FleetStats:
     group_labels: np.ndarray | None = None   # e.g. capacitor sizes (G,)
     wall_s: float = 0.0               # accumulated replay wall clock
     peak_lane_bytes: int = 0          # max per-chunk lane-buffer bytes
-
-    # -- construction ----------------------------------------------------
-    @classmethod
-    def from_parts(cls, parts: tuple, edges: dict,
-                   group_labels=None) -> "FleetStats":
-        """Build from the ``(psums, pmins, pmaxs)`` of
-        :func:`reduce_lane_outputs` (device arrays or numpy)."""
-        psums, pmins, pmaxs = parts
-        np_ = {k: np.asarray(v) for k, v in psums.items()}
-        return cls(
-            count=np_["count"], completed=np_["completed"],
-            sums={ch: np_[f"{ch}:sum"] for ch in STAT_CHANNELS},
-            sumsqs={ch: np_[f"{ch}:sumsq"] for ch in STAT_CHANNELS},
-            mins={ch: np.asarray(v) for ch, v in pmins.items()},
-            maxs={ch: np.asarray(v) for ch, v in pmaxs.items()},
-            hists={ch: np_[f"{ch}:hist"] for ch in STAT_CHANNELS},
-            edges={ch: np.asarray(e) for ch, e in edges.items()},
-            class_sums=np_["class_sums"],
-            group_labels=None if group_labels is None
-            else np.asarray(group_labels))
 
     # -- associative accumulation ----------------------------------------
     def merge(self, other: "FleetStats") -> "FleetStats":
@@ -390,12 +291,11 @@ class FleetStats:
 def stats_from_outputs(out: dict, edges: dict, group_id=None,
                        n_groups: int = 1,
                        group_labels=None) -> FleetStats:
-    """Reference reduction: the same statistics computed from
-    *materialized* per-lane outputs with plain numpy.  This is the
-    validation oracle for the in-jit streamed reduction (and a
-    convenience for small fleets): ``fleet_sweep(..., reduce="stats")``
-    must be bit-exact on sums/counts and bin-exact on histograms against
-    this, per the differential tests."""
+    """The statistics of materialized per-lane outputs, in plain numpy:
+    the fold every streamed sweep applies to each chunk, and the oracle
+    ``fleet_sweep(..., reduce="stats")`` is checked against (bit-exact
+    on sums/counts and bin-exact on histograms, per the differential
+    tests)."""
     stuck = np.asarray(out["stuck"])
     n = stuck.shape[0]
     gid = (np.zeros(n, np.int64) if group_id is None

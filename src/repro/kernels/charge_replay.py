@@ -47,8 +47,10 @@ mid-row without a control-flow boundary.
 
 The Pallas kernel (``pallas_replay``) runs the same ``event_replay`` body
 one lane per grid step (scalar state in registers, the plan broadcast to
-every program); on CPU it executes in interpret mode for validation, which
-is also how the differential harness pins it against the XLA path.
+every program).  It runs only in interpret mode, which is how the
+differential harness pins it against the XLA path: its state is float64,
+and Mosaic, the TPU Pallas compiler, has no float64 tiling, so compiling
+it for a TPU raises (:func:`pallas_replay`).
 """
 
 from __future__ import annotations
@@ -910,14 +912,23 @@ def pallas_replay(rows, caps, rem0, trace_cum, tail_s, charge_cum,
                   adaptive: bool, parametric: bool, shared_rows: bool,
                   enable_fast: bool = True, has_burn: bool = True,
                   has_send: bool = False,
-                  chunk: int = EVENT_CHUNK, interpret: bool = True) -> dict:
+                  chunk: int = EVENT_CHUNK, interpret: bool = False) -> dict:
     """The fused replay as a Pallas kernel: grid over lanes, one program
     per lane running the scalar ``event_replay`` with the plan broadcast
     (``shared_rows``) or blocked per lane.  Scalar sweep knobs travel as
-    (1,)-shaped operands.  On CPU (``interpret=True``) the same kernel
-    body runs under the Pallas interpreter, which is how the differential
-    harness validates it against the XLA path."""
+    (1,)-shaped operands.  Only ``interpret=True`` runs: the Pallas
+    interpreter executes the kernel body, which is how the differential
+    harness validates it against the XLA path.  Compiling it raises
+    ``ValueError`` before lowering, because every operand and output is
+    float64 and Mosaic cannot tile float64 blocks."""
     from jax.experimental import pallas as pl
+
+    if not interpret:
+        raise ValueError(
+            "the Pallas lane replay kernel cannot be compiled: its "
+            "operands and outputs are float64, which Mosaic (the TPU "
+            "Pallas compiler) cannot lower; run it with interpret=True, "
+            "or replay with backend='xla'")
 
     keys = tuple(sorted(rows))
     n_lanes = caps.shape[0]

@@ -121,8 +121,9 @@ def charge_replay(rows, caps, rem0, trace_cum, tail_s, charge_cum,
                   interpret: bool | None = None):
     """Fused stochastic charge-loop replay as a Pallas lane kernel (one
     grid step per device lane; ``repro.kernels.charge_replay``).  The
-    default XLA event stream lives in ``repro.core.fleetsim``; this entry
-    point is the accelerator form, validated in interpret mode on CPU."""
+    default XLA event stream lives in ``repro.core.fleetsim``.  Off the
+    TPU this runs in interpret mode; on the TPU it raises ``ValueError``,
+    because Mosaic cannot lower the kernel's float64 state."""
     if interpret is None:
         interpret = not on_tpu()
     fn = _charge_replay_jit(adaptive, parametric, shared_rows,

@@ -8,24 +8,7 @@ host devices while tests and benches must see the single real device.
 from __future__ import annotations
 
 import jax
-
-
-def axis_type_kwargs(n_axes: int) -> dict:
-    """Version-compat shim for ``jax.make_mesh(..., axis_types=...)``.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types`` kwarg) only exist on
-    newer JAX; older releases (<= 0.4.x) treat every axis as Auto already,
-    so omitting the kwarg is semantically identical there.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types on any supported JAX version."""
-    return jax.make_mesh(shape, axes, **axis_type_kwargs(len(axes)))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -34,12 +17,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     over DCN, proving the cross-pod sharding lowers."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(shape=(1, 1), axes=("data", "model")):
     """Small mesh over whatever devices exist (CPU tests, examples)."""
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_fleet_mesh(n_shards: int | None = None):
@@ -49,48 +32,7 @@ def make_fleet_mesh(n_shards: int | None = None):
     Defaults to every available device; on a single-chip host this is a
     ``(1,)`` mesh, which exercises the identical sharded code path."""
     n = len(jax.devices()) if n_shards is None else n_shards
-    return compat_make_mesh((n,), ("devices",))
-
-
-def compat_shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` on new JAX, ``jax.experimental.shard_map`` on old.
-
-    The experimental module is the only home of ``shard_map`` up to ~0.4.x;
-    newer releases promote it to the top-level namespace (and will eventually
-    drop the experimental alias), so probe the stable location first.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    # check_rep=False where supported: the fleet replay is embarrassingly
-    # parallel (no collectives), and old-JAX replication inference hits a
-    # known fixpoint bug on scan carries that pass through untouched in
-    # some compiles (e.g. fleetsim's wasted channel on the deterministic
-    # path) -- the workaround the error message itself recommends.
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:   # newer jax dropped the kwarg (check_vma era)
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
-def fleet_all_reduce(parts, axis_name: str = "devices"):
-    """All-reduce the fleet-statistics partials of
-    ``repro.core.fleetstats.reduce_lane_outputs`` across a mesh axis.
-
-    ``parts`` is the ``(psums, pmins, pmaxs)`` triple, split by reduction
-    operator: sums/counts/histograms combine with ``psum``, the exact
-    extremes with ``pmin``/``pmax``.  After the reduce every shard holds
-    the identical fleet summary (replicated, ``out_specs=P()``), so a
-    sharded sweep returns one fixed-size result instead of per-lane
-    arrays -- the cross-shard half of the memory-flat reduction (lane
-    chunking on the host is the other half)."""
-    from jax import lax, tree_util
-
-    psums, pmins, pmaxs = parts
-    return (tree_util.tree_map(lambda x: lax.psum(x, axis_name), psums),
-            tree_util.tree_map(lambda x: lax.pmin(x, axis_name), pmins),
-            tree_util.tree_map(lambda x: lax.pmax(x, axis_name), pmaxs))
+    return jax.make_mesh((n,), ("devices",), axis_types=(AxisType.Auto,))
 
 
 def dp_axes(mesh) -> tuple:

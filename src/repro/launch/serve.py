@@ -15,6 +15,7 @@ import numpy as np
 from ..configs import ARCHS, get_config
 from ..models import get_model
 from ..serving import Request, ServeEngine
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--state-dir", default=None)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.scaled_down()

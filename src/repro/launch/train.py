@@ -31,6 +31,7 @@ from ..configs import ARCHS, get_config
 from ..data import token_batches
 from ..models import get_model
 from ..optim import adamw, cosine_schedule
+from .compile_cache import enable_compile_cache
 from .mesh import make_host_mesh
 from .shardings import tree_shardings
 
@@ -140,6 +141,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-interval", type=int, default=20)
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.scaled_down()

@@ -70,6 +70,19 @@ class ServeEngine:
         return Request(rid, c["prompt"], c["max_new"],
                        list(c.get("generated", [])))
 
+    def prefill(self, token_lists: list[list]) -> tuple:
+        """Feed the batch's tokens through the decode step one position at
+        a time, up to the shortest list; returns the logits at that last
+        position, ``(B, vocab_padded)``, and the KV cache."""
+        min_done = min(len(t) for t in token_lists)
+        assert min_done > 0, "requests must have non-empty prompts"
+        cache = self.api.init_cache(self.cfg, len(token_lists), self.max_len)
+        last_logits = None
+        for pos in range(min_done):
+            tok = jnp.asarray([t[pos] for t in token_lists], jnp.int32)
+            last_logits, cache = self._decode(self.params, cache, tok, pos)
+        return last_logits, cache
+
     def run(self, requests: list[Request], greedy: bool = True,
             fail_after_tokens: int | None = None) -> dict:
         """Decode a batch of same-length-prompt requests to completion.
@@ -80,7 +93,6 @@ class ServeEngine:
         for r in requests:
             self.submit(r)
         requests = [self.recover(r.rid) for r in requests]
-        b = len(requests)
         plens = {len(r.prompt) for r in requests}
         if len(plens) > 1:
             raise ValueError(
@@ -95,12 +107,7 @@ class ServeEngine:
         # idempotent re-prefill of prompt + committed tokens
         done_tokens = [r.prompt + r.generated for r in requests]
         min_done = min(len(t) for t in done_tokens)
-        assert min_done > 0, "requests must have non-empty prompts"
-        cache = self.api.init_cache(self.cfg, b, self.max_len)
-        last_logits = None
-        for pos in range(min_done):
-            tok = jnp.asarray([t[pos] for t in done_tokens], jnp.int32)
-            last_logits, cache = self._decode(self.params, cache, tok, pos)
+        last_logits, cache = self.prefill(done_tokens)
         emitted = 0
         pos = min_done - 1           # position of the last token fed
         while not all(r.done for r in requests):

@@ -66,7 +66,8 @@ _RADIO = OP_CLASSES.index("radio")
 def trace_window(cum, r0, r1, fallback):
     """Windowed sum of a cumulative trace over (r0, r1] with per-entry
     fallback past the end -- the same gather/over arithmetic as the scan's
-    ``trace_window`` so dead time matches to float identity."""
+    ``trace_window`` and the host's ``fleetsim._dead_time``, so charges
+    and dead time match to float identity."""
     if cum is None:
         return (r1 - r0) * fallback
     last = len(cum) - 1
@@ -461,7 +462,15 @@ def reference_replay(rows: dict, cap: float, rem0: float, *,
             if send_b > 0.0:
                 s.sent += 1.0
 
-    return dict(live=s.live, reboots=s.reboots, dead=s.dead,
+    # Without SEND rows nothing waits on the clock, and dead time is the
+    # recharge window over all of the lane's reboots: one window, as the
+    # replay computes it on the host (fleetsim._dead_time).  The row-wise
+    # sum above is the same quantity in another rounding.
+    sends = radio is not None and any(int(k) == KIND_SEND
+                                      for k in rows["kind"])
+    dead = s.dead if sends else trace_window(recharge_cum, 0.0, s.reboots,
+                                             tail_s)
+    return dict(live=s.live, reboots=s.reboots, dead=dead,
                 classes=s.classes, wasted=s.wasted, stuck=s.stuck,
                 belief=s.bhat, useful=s.useful,
                 wasted_total=s.wasted_total, overhead=s.overhead,

@@ -15,7 +15,7 @@ import pytest
 from repro.core import (Conv2D, DenseFC, FleetStats, MaxPool2D, SimNet,
                         SparseFC, STAT_CHANNELS, capacitor_sweep,
                         fleet_sweep, replay_plans, stats_from_outputs)
-from repro.core.energy import CLOCK_HZ, JOULES_PER_CYCLE, OP_CLASSES
+from repro.core.energy import JOULES_PER_CYCLE, OP_CLASSES
 
 
 @pytest.fixture(scope="module")
@@ -35,37 +35,13 @@ def small_net():
     return net, x
 
 
-def _oracle_out(r):
-    """Rebuild the replay output dict ``stats_from_outputs`` expects from
-    a materialized ``FleetSweepResult``.  ``live`` is reconstructed from
-    ``live_s`` (the result surface divides by CLOCK_HZ = 16e6, not a
-    power of two), so live-derived channels carry one ulp of round-trip
-    noise; the bit-exact comparison against raw outputs lives in
-    :func:`test_replay_plans_stats_bitexact_raw`."""
-    n = r.n_devices
-    zeros = np.zeros(n)
-    return {
-        "live": r.live_s * CLOCK_HZ,
-        "dead": r.dead_s,
-        "reboots": r.reboots,
-        "wasted": zeros if r.wasted_cycles is None else r.wasted_cycles,
-        "belief": zeros if r.belief_cycles is None else r.belief_cycles,
-        "stuck": ~r.completed,
-        "classes": np.zeros((n, len(OP_CLASSES))),
-        "tx_bytes": zeros if r.tx_bytes is None else r.tx_bytes,
-        "msgs_sent": zeros if r.msgs_sent is None else r.msgs_sent,
-        "msgs_deferred": zeros if r.msgs_deferred is None
-        else r.msgs_deferred,
-    }
-
-
-def _assert_stats_equal(a, b, *, skip_class_sums=False, approx=()):
+def _assert_stats_equal(a, b, *, approx=()):
     """Bit-exact equality on every statistic; channels in ``approx``
     compare to 1e-12 relative on the fp moments (sums/sumsqs) and exactly
-    on everything else.  ``approx`` covers two legitimate ulp sources:
-    oracle inputs reconstructed through a lossy round-trip, and fp
-    accumulation order differing across chunk partitions (min/max,
-    counts and histograms are truly associative and stay exact)."""
+    on everything else.  ``approx`` covers the one legitimate ulp
+    source: fp accumulation order differing across chunk partitions
+    (min/max, counts and histograms are truly associative and stay
+    exact)."""
     assert np.array_equal(a.count, b.count)
     assert np.array_equal(a.completed, b.completed)
     for ch in STAT_CHANNELS:
@@ -80,11 +56,10 @@ def _assert_stats_equal(a, b, *, skip_class_sums=False, approx=()):
             assert np.array_equal(a.mins[ch], b.mins[ch]), ch
             assert np.array_equal(a.maxs[ch], b.maxs[ch]), ch
         assert np.array_equal(a.hists[ch], b.hists[ch]), ch
-    if not skip_class_sums:
-        if approx:
-            assert np.allclose(a.class_sums, b.class_sums, rtol=1e-12)
-        else:
-            assert np.array_equal(a.class_sums, b.class_sums)
+    if approx:
+        assert np.allclose(a.class_sums, b.class_sums, rtol=1e-12)
+    else:
+        assert np.array_equal(a.class_sums, b.class_sums)
 
 
 def test_replay_plans_stats_bitexact_raw(small_net):
@@ -132,9 +107,8 @@ def test_replay_plans_stats_bitexact_raw(small_net):
 def test_stats_bitexact_vs_materialized(small_net, strategy, policy, cv):
     """Unchunked ``reduce="stats"`` shares the legacy samplers with
     ``reduce="none"``, so the streamed statistics must match the numpy
-    oracle over the materialized outputs (bit-exact on the directly
-    surfaced channels; the live-derived ones round-trip through
-    ``live_s`` and compare to 1e-12)."""
+    oracle over the materialized raw outputs bit for bit, on every
+    channel and the per-class sums."""
     net, x = small_net
     kw = dict(n_devices=48, seed=3, policy=policy,
               charge_cv=cv, charge_reboots=16 if cv > 0 else 0)
@@ -142,11 +116,8 @@ def test_stats_bitexact_vs_materialized(small_net, strategy, policy, cv):
         kw.update(theta=0.5, batch_rows=4, belief_alpha=0.25)
     r = fleet_sweep(net, x, strategy, "1mF", **kw)
     st = fleet_sweep(net, x, strategy, "1mF", reduce="stats", **kw)
-    ref = stats_from_outputs(_oracle_out(r), st.edges)
-    _assert_stats_equal(st, ref, skip_class_sums=True,
-                        approx=("live_cycles", "total_s"))
-    # class_sums are not on the result surface; pin them through the
-    # energy identity instead: live cycles are the energy channel.
+    _assert_stats_equal(st, stats_from_outputs(r.outputs, st.edges))
+    # live cycles are the energy channel
     assert np.allclose(st.energy_j_sum,
                        r.energy_j[r.completed].sum(), rtol=1e-12)
     assert st.summary()["devices"] == 48
@@ -198,8 +169,8 @@ def test_chunked_none_reduce_concatenates_bitexact(small_net):
 
 
 def test_mesh_stats_match_unmeshed(small_net):
-    """The shard_map path all-reduces per-shard partials into the same
-    fleet summary the unmeshed reduction produces."""
+    """The shard_map path gives the fleet summary the unmeshed sweep
+    gives, bit for bit, unchunked and chunked."""
     from repro.launch.mesh import make_fleet_mesh
 
     net, x = small_net
@@ -212,6 +183,61 @@ def test_mesh_stats_match_unmeshed(small_net):
                       lane_chunk=17, **kw)
     sc = fleet_sweep(net, x, "sonic", "1mF", lane_chunk=17, **kw)
     _assert_stats_equal(sc, smc)
+
+
+MESH_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys, json
+sys.path.insert(0, sys.argv[1])
+import numpy as np
+from repro.core import (Conv2D, DenseFC, MaxPool2D, SimNet, STAT_CHANNELS,
+                        fleet_sweep)
+from repro.launch.mesh import make_fleet_mesh
+
+rng = np.random.default_rng(0)
+net = SimNet([
+    Conv2D(rng.normal(size=(3, 1, 3, 3)).astype(np.float32),
+           rng.normal(size=3).astype(np.float32)),
+    MaxPool2D(2),
+    DenseFC((rng.normal(size=(8, 75)) * 0.1).astype(np.float32),
+            rng.normal(size=8).astype(np.float32), relu=False),
+], input_shape=(1, 12, 12), name="meshnet")
+x = rng.normal(size=(1, 12, 12)).astype(np.float32)
+kw = dict(n_devices=256, seed=3, trace_reboots=8, charge_cv=0.25,
+          charge_reboots=16, reduce="stats")
+differ = []
+for lane_chunk in (None, 96):
+    one = fleet_sweep(net, x, "sonic", "1mF", lane_chunk=lane_chunk, **kw)
+    four = fleet_sweep(net, x, "sonic", "1mF", lane_chunk=lane_chunk,
+                       mesh=make_fleet_mesh(4), **kw)
+    for name in ("count", "completed", "class_sums"):
+        if not np.array_equal(getattr(one, name), getattr(four, name)):
+            differ.append(f"{lane_chunk}/{name}")
+    for ch in STAT_CHANNELS:
+        for k in ("sums", "sumsqs", "mins", "maxs", "hists"):
+            if not np.array_equal(getattr(one, k)[ch], getattr(four, k)[ch]):
+                differ.append(f"{lane_chunk}/{ch}:{k}")
+print(json.dumps({"differ": differ, "count": float(four.count[0])}))
+"""
+
+
+def test_mesh_stats_match_unmeshed_on_four_devices():
+    """Sharded over four devices, the streamed statistics equal the
+    unmeshed sweep's bit for bit, floating-point moments included
+    (unchunked and chunked)."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    proc = subprocess.run([sys.executable, "-c", MESH_SCRIPT, src],
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["count"] == 256
+    assert out["differ"] == []
 
 
 def test_capacitor_sweep_stats_groups(small_net):

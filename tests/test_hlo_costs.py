@@ -18,12 +18,12 @@ import sys, json
 sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp
 from jax import lax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.launch import hlo_costs
-from repro.launch.mesh import compat_make_mesh
 
 out = {}
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 
 # 1) nested scan: 3 x 5 = 15 matmuls of 64^3
 W = jnp.zeros((64, 64), jnp.float32)

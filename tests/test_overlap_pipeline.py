@@ -1,8 +1,8 @@
 """Differential tests for the overlapped streaming replay pipeline.
 
 The contract under test: ``prefetch >= 1`` (the default double-buffered
-producer/consumer pipeline of ``_chunked_replay``, with device-resident
-stats accumulation via ``fleetstats.merge_parts``) must be *bit-exact*
+producer/consumer pipeline of ``_chunked_replay``, folding each chunk on
+the host while the next replays) must be *bit-exact*
 against ``prefetch=0`` -- the legacy fully synchronous chunk loop -- on
 every output channel, for both ``reduce="stats"`` (same chunk partials,
 same left-fold merge order) and ``reduce="none"`` (same concatenated
@@ -10,8 +10,8 @@ lanes), across the strategy x policy x charge-jitter grid, non-divisible
 final chunks, the PlanSet plan-mode chunk path, `capacitor_sweep`, and
 ``replay_plans``' explicit per-device trace matrices (which since this
 PR stream through ``lane_chunk`` by per-chunk slicing, bit-exact vs the
-unchunked call).  The in-jit stats accumulator is additionally pinned
-associative against the host-side ``FleetStats`` merge.
+unchunked call).  The chunk partials' ``FleetStats`` merge is
+additionally pinned against one fold over all lanes.
 """
 
 import numpy as np
@@ -224,59 +224,40 @@ def test_replay_plans_seeded_chunked_bitexact(small_net):
         assert a.completed == b.completed
 
 
-def test_merge_parts_matches_host_merge_and_associates(small_net):
-    """The in-jit accumulator is the host merge: a left fold of
-    ``merge_parts`` over chunk partials equals ``FleetStats.from_parts``
-    + ``merge`` bit for bit, and the merge associates (count/hist/
-    extreme channels exactly; fp moments to 1e-12 under
-    reassociation)."""
-    import jax
-
-    from repro.core.fleetsim import _jit_reduce_only, _x64
-    from repro.core.fleetstats import default_stat_edges, merge_parts
+def test_chunk_partials_merge_like_one_fold():
+    """Folding chunks of lanes separately and merging the partials left to
+    right gives the one fold over all lanes: counts, histograms and
+    extremes exactly, fp moments to 1e-12 (another summation order), and
+    the merge associates."""
+    from repro.core.fleetstats import default_stat_edges, stats_from_outputs
 
     rng = np.random.default_rng(3)
     edges = default_stat_edges(5e5, 1e4, 0.5, 16)
-    n_groups, n = 2, 60
-    parts = []
-    with _x64():
-        import jax.numpy as jnp
-        jedges = {k: jnp.asarray(v) for k, v in edges.items()}
-        for i in range(3):
-            out = {
-                "live": jnp.asarray(rng.integers(1, 10**6, n) * 1.0),
-                "dead": jnp.asarray(rng.random(n) * 50),
-                "reboots": jnp.asarray(rng.integers(0, 99, n) * 1.0),
-                "wasted": jnp.asarray(rng.integers(0, 500, n) * 1.0),
-                "belief": jnp.asarray(rng.random(n) * 1e4),
-                "stuck": jnp.asarray(rng.random(n) < 0.1),
-                "classes": jnp.asarray(
-                    rng.random((n, len(OP_CLASSES))) * 100),
-            }
-            gid = jnp.asarray(rng.integers(0, n_groups, n).astype(
-                np.int32))
-            vld = jnp.asarray(rng.random(n) < 0.9)
-            parts.append(_jit_reduce_only(n_groups)(
-                out, gid, vld, jedges))
-        a, b, c = parts
-        folded = merge_parts(merge_parts(a, b), c)
-        refolded = merge_parts(a, merge_parts(b, c))
-    host = FleetStats.from_parts(a, edges).merge(
-        FleetStats.from_parts(b, edges)).merge(
-        FleetStats.from_parts(c, edges))
-    injit = FleetStats.from_parts(jax.tree_util.tree_map(
-        np.asarray, folded), edges)
-    _assert_stats_bitexact(host, injit)
-    assoc = FleetStats.from_parts(jax.tree_util.tree_map(
-        np.asarray, refolded), edges)
-    assert np.array_equal(injit.count, assoc.count)
-    assert np.array_equal(injit.completed, assoc.completed)
-    for ch in STAT_CHANNELS:
-        np.testing.assert_allclose(injit.sums[ch], assoc.sums[ch],
-                                   rtol=1e-12)
-        assert np.array_equal(injit.hists[ch], assoc.hists[ch]), ch
-        assert np.array_equal(injit.mins[ch], assoc.mins[ch]), ch
-        assert np.array_equal(injit.maxs[ch], assoc.maxs[ch]), ch
+    n_groups, n = 2, 180
+    out = {
+        "live": rng.integers(1, 10**6, n) * 1.0,
+        "dead": rng.random(n) * 50,
+        "reboots": rng.integers(0, 99, n) * 1.0,
+        "wasted": rng.integers(0, 500, n) * 1.0,
+        "belief": rng.random(n) * 1e4,
+        "stuck": rng.random(n) < 0.1,
+        "classes": rng.random((n, len(OP_CLASSES))) * 100,
+    }
+    gid = rng.integers(0, n_groups, n)
+    a, b, c = (stats_from_outputs({k: v[sl] for k, v in out.items()},
+                                  edges, gid[sl], n_groups)
+               for sl in (slice(0, 60), slice(60, 120), slice(120, n)))
+    whole = stats_from_outputs(out, edges, gid, n_groups)
+    left, right = a.merge(b).merge(c), a.merge(b.merge(c))
+    for st in (left, right):
+        assert np.array_equal(st.count, whole.count)
+        assert np.array_equal(st.completed, whole.completed)
+        for ch in STAT_CHANNELS:
+            np.testing.assert_allclose(st.sums[ch], whole.sums[ch],
+                                       rtol=1e-12)
+            assert np.array_equal(st.hists[ch], whole.hists[ch]), ch
+            assert np.array_equal(st.mins[ch], whole.mins[ch]), ch
+            assert np.array_equal(st.maxs[ch], whole.maxs[ch]), ch
 
 
 def test_event_chunk_auto_matches_default(small_net):
@@ -304,3 +285,33 @@ def test_prefetch_validation(small_net):
     with pytest.raises(ValueError, match="prefetch"):
         fleet_sweep(net, x, "sonic", "1mF", n_devices=8, lane_chunk=4,
                     prefetch=-1)
+
+
+@pytest.mark.parametrize("prefetch", [0, 1])
+def test_replay_operands_are_float64(small_net, monkeypatch, prefetch):
+    """Every chunk's replay call gets float64 operands, including those
+    the producer thread uploads (64-bit mode is per thread, so the
+    producer enters its own), and never a float32 one."""
+    import jax
+
+    import repro.core.fleetsim as fs
+
+    real, dtypes = fs._jit_replay, []
+
+    def spy(*key):
+        fn = real(*key)
+
+        def call(*args):
+            dtypes.append({np.dtype(a.dtype)
+                           for a in jax.tree_util.tree_leaves(args)
+                           if np.issubdtype(a.dtype, np.floating)})
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(fs, "_jit_replay", spy)
+    net, x = small_net
+    fleet_sweep(net, x, "sonic", "1mF", n_devices=96, lane_chunk=32, seed=3,
+                charge_cv=0.25, charge_reboots=8, prefetch=prefetch,
+                reduce="stats")
+    assert len(dtypes) == 3
+    assert all(d == {np.dtype(np.float64)} for d in dtypes), dtypes
