@@ -12,10 +12,6 @@ adaptive_risk -- (beyond the paper) energy-adaptive commit batching vs
              adaptive/fixed energy ratio per jitter cv, for the
              single-row window, the cross-charge window, and the
              cross-charge window with EWMA belief recalibration.
-bench_history -- the cross-PR benchmark trajectory (BENCH_history.jsonl)
-             as a small-multiples plot; ``python benchmarks/paper_figs.py
-             --bench-history out.png`` renders it standalone (the CI
-             bench-smoke artifact).
 
 The compressed network used by fig9-12 is a fixed, documented configuration
 (separate conv1, prune conv2/FCs) matching Table 2's structure; the full
@@ -331,122 +327,6 @@ def adaptive_risk() -> list[tuple]:
     return rows
 
 
-# --------------------------------------------------------------------------
-# Cross-PR benchmark trajectory (BENCH_history.jsonl -> plot)
-# --------------------------------------------------------------------------
-
-#: Validated categorical palette (dataviz reference instance, light mode);
-#: fixed slot order -- a series keeps its hue across runs and filters.
-_SERIES_COLORS = ("#2a78d6", "#eb6834", "#1baf7a", "#eda100")
-_TEXT = "#0b0b0b"
-_MUTED = "#52514e"
-_GRID = "#d9d8d3"
-
-# the history file's path (and line format) is owned by the module that
-# writes it; the fallback covers `python benchmarks/paper_figs.py` runs
-# where the repo root is not on sys.path
-try:
-    from benchmarks.fleet import HISTORY_PATH
-except ImportError:
-    from fleet import HISTORY_PATH
-
-
-def bench_history(out_path: Path | None = None,
-                  history: Path = HISTORY_PATH) -> list[tuple]:
-    """Render the cross-PR perf trajectory accumulated in
-    ``BENCH_history.jsonl`` (one compact line per bench run, appended by
-    ``benchmarks/fleet.py:write_bench``) as a small-multiples plot: one
-    panel per metric (the metrics have incompatible units, so they never
-    share an axis), runs on a shared run-index axis, full runs as filled
-    markers and warm smoke runs as open ones (shape, not color, carries
-    the run-config difference)."""
-    all_runs = []
-    if history.exists():
-        for ln in history.read_text().splitlines():
-            ln = ln.strip()
-            if ln:
-                all_runs.append(json.loads(ln))
-    rows = [("history/bench_runs", len(all_runs),
-             f"lines in {history.name} (schema(s) "
-             f"{sorted({r.get('schema') for r in all_runs})})")]
-    if not all_runs:
-        return rows
-    # Runs are only trajectory-comparable within one (devices, warm)
-    # group: a warm 200-device smoke run and a cold 1000-device full run
-    # measure different things, and mixing them under one line corrupts
-    # the plot.  Track the group of the latest run and skip the rest.
-    group = lambda r: (r.get("devices"), bool(r.get("warm")))
-    ref = group(all_runs[-1])
-    runs = [r for r in all_runs if group(r) == ref]
-    skipped = len(all_runs) - len(runs)
-    rows.append(("history/comparable_runs", len(runs),
-                 f"group devices={ref[0]} warm={ref[1]}; "
-                 f"skipped {skipped} non-comparable line(s)"))
-    try:
-        import matplotlib
-        matplotlib.use("Agg")
-        import matplotlib.pyplot as plt
-    except ImportError:
-        rows.append(("history/plot", 0, "matplotlib unavailable; skipped"))
-        return rows
-
-    if out_path is None:
-        RESULTS.mkdir(parents=True, exist_ok=True)
-        out_path = RESULTS / "bench_history.png"
-    xs = list(range(1, len(runs) + 1))
-    warm = [bool(r.get("warm")) for r in runs]
-
-    def panel(ax, title, series):
-        """series: list of (label, color, values) with None gaps."""
-        for label, color, ys in series:
-            pts = [(x, y, w) for x, y, w in zip(xs, ys, warm)
-                   if y is not None]
-            if not pts:
-                continue
-            ax.plot([p[0] for p in pts], [p[1] for p in pts],
-                    color=color, lw=1.8, zorder=3)
-            for x, y, w in pts:
-                ax.plot(x, y, "o", ms=6, mfc="white" if w else color,
-                        mec=color, mew=1.6, zorder=4)
-            ax.annotate(label, (pts[-1][0], pts[-1][1]),
-                        xytext=(5, 0), textcoords="offset points",
-                        fontsize=8, color=_TEXT, va="center")
-        ax.set_title(title, fontsize=9, color=_TEXT, loc="left")
-        ax.grid(True, color=_GRID, lw=0.6, zorder=0)
-        ax.tick_params(colors=_MUTED, labelsize=8)
-        for sp in ax.spines.values():
-            sp.set_color(_GRID)
-        ax.set_xticks(xs)
-        ax.margins(x=0.12)
-
-    fig, axes = plt.subplots(2, 2, figsize=(9, 6), constrained_layout=True)
-    strategies = sorted({s for r in runs
-                         for s in (r.get("speedup_vs_scalar") or {})})
-    panel(axes[0][0], "replay speedup vs scalar (x)",
-          [(s, _SERIES_COLORS[i % len(_SERIES_COLORS)],
-            [(r.get("speedup_vs_scalar") or {}).get(s) for r in runs])
-           for i, s in enumerate(strategies)])
-    panel(axes[0][1], "capacitor-sweep lanes / s",
-          [("lanes/s", _SERIES_COLORS[0],
-            [r.get("capsweep_lanes_per_sec") for r in runs])])
-    panel(axes[1][0], "worst adaptive/fixed energy ratio (theta<=1, a=0)",
-          [("ratio", _SERIES_COLORS[0],
-            [r.get("risk_worst_energy_ratio") for r in runs])])
-    panel(axes[1][1], "EWMA recovery of jitter-eroded win (best alpha)",
-          [("recovery", _SERIES_COLORS[0],
-            [r.get("risk_ewma_recovery_max") for r in runs])])
-    axes[1][0].axhline(1.0, color=_MUTED, lw=0.8, ls="--", zorder=1)
-    axes[1][1].axhline(0.5, color=_MUTED, lw=0.8, ls="--", zorder=1)
-    fig.suptitle("benchmarks/fleet.py trajectory (open markers = warm "
-                 "smoke runs)", fontsize=10, color=_TEXT)
-    for ax in axes[1]:
-        ax.set_xlabel("bench run", fontsize=8, color=_MUTED)
-    fig.savefig(out_path, dpi=150, facecolor="#fcfcfb")
-    plt.close(fig)
-    rows.append(("history/plot", 1, f"wrote {out_path}"))
-    return rows
-
-
 def svm_vs_dnn() -> list[tuple]:
     """Sec. 5.1: no SVM model is competitive with the DNNs on IMpJ
     (paper: 2x worse on MNIST, 8x on HAR)."""
@@ -489,27 +369,15 @@ def run() -> list[tuple]:
     RESULTS.mkdir(parents=True, exist_ok=True)
     rows = []
     for fn in (fig1_2, table2, fig4_5, fig9, fig10, fig11, fig12,
-               adaptive_risk, svm_vs_dnn, bench_history):
+               adaptive_risk, svm_vs_dnn):
         rows.extend(fn())
     return rows
 
 
 def main() -> None:
     import argparse
-    import sys as _sys
 
-    ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--bench-history", metavar="OUT.png", default=None,
-                    help="render only the BENCH_history.jsonl trajectory "
-                         "plot to this path (the CI bench-smoke artifact)")
-    args = ap.parse_args()
-    if args.bench_history:
-        rows = bench_history(out_path=Path(args.bench_history))
-        for n, v, d in rows:
-            print(f'{n},{v},"{d}"')
-        if not any(n == "history/plot" and v == 1 for n, v, _d in rows):
-            _sys.exit("bench-history plot was not rendered")
-        return
+    argparse.ArgumentParser(description=__doc__).parse_args()
     for n, v, d in run():
         print(f'{n},{v},"{d}"')
 

@@ -1,5 +1,5 @@
-"""Benchmark entry point: one section per paper table/figure + the fleet
-and roofline analyses.  Prints ``name,value,derived`` CSV.
+"""Benchmark entry point: one section per paper table/figure + the
+kernel and roofline analyses.  Prints ``name,value,derived`` CSV.
 
   PYTHONPATH=src python -m benchmarks.run [--section fig9|roofline|...]
 """
@@ -19,15 +19,14 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--section", default="all",
-                    help="all | paper | fleet | kernels | roofline")
+                    help="all | paper | kernels | roofline")
     args = ap.parse_args()
 
-    from benchmarks import fleet, kernels_bench, paper_figs, roofline
+    from benchmarks import kernels_bench, paper_figs, roofline
     from repro.launch.compile_cache import enable_compile_cache
 
     sections = {
         "paper": paper_figs.run,
-        "fleet": fleet.run,
         "kernels": kernels_bench.run,
         "roofline": roofline.run,
     }

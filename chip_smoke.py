@@ -69,7 +69,7 @@ REHEARSAL = Sizes(lanes=4096, lane_chunk=1024, ref_lanes=8,
                   mesh_lanes=2048, mesh_chunk=1024, requests=4,
                   prompt_len=8, max_new=4, full_model=False)
 
-#: The fleet benchmark's stochastic energy model (benchmarks/fleet.py).
+#: The stochastic energy model of the fleet benchmark's stoch traffic.
 STRATEGIES = ("sonic", "tails")
 POWER = "1mF"
 FLEET_KW = dict(trace_reboots=64, charge_cv=0.25, charge_reboots=256,

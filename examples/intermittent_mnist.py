@@ -216,9 +216,7 @@ def main():
     print("(single-row chunks bound each rollback to one row; the "
           "cross-charge window wins big on calm charges and bleeds on "
           "jittery ones; EWMA recalibration claws most of that back -- "
-          "1 cycle = {:.1e} J.  benchmarks/fleet.py records the full "
-          "theta x cv x alpha frontier in BENCH_fleet.json.)"
-          .format(JOULES_PER_CYCLE))
+          "1 cycle = {:.1e} J.)".format(JOULES_PER_CYCLE))
 
     # Fleet-scale queries: past ~1e5 devices the per-lane result arrays
     # (and the per-lane input traces behind them) stop fitting anywhere,
@@ -227,7 +225,7 @@ def main():
     # chunk by chunk and lane_chunk= streams the device axis
     # through one constant-size buffer -- peak memory is set by
     # the chunk, not the fleet, so the same call scales to 1e7 lanes
-    # (the scaling curve lives in BENCH_fleet.json under fleet_scaling).
+    # (FleetStats.peak_lane_bytes records the bound).
     big = 1_000_000
     st = fleet_sweep(net, x, "sonic", "1mF", n_devices=big, seed=42,
                      reduce="stats", lane_chunk=8192)

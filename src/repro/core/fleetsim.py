@@ -955,25 +955,19 @@ def _vmap_replay(shared_rows, adaptive: bool, parametric: bool,
     ``s_real`` (real row count) are per-lane traced operands of the fused
     event stream; the legacy paths ignore them."""
     import jax
-    if shared_rows == "plan":
-        return jax.vmap(
-            lambda rows, cap, rem0, tc, ts, ccum, nf, sr, theta, window,
-            alpha, conf, radio, pidx:
-            _scan_one(rows, cap, rem0, tc, ts, ccum, nf, sr, theta,
-                      window, alpha, conf, radio, adaptive, parametric,
-                      stochastic, backend, chunk, enable_fast, has_burn,
-                      has_send, plan_idx=pidx),
-            in_axes=(None, 0, 0, 0, 0, 0, 0, 0, None, None, None, 0,
-                     None, 0))
+
+    def fleet_replay(rows, cap, rem0, tc, ts, ccum, nf, sr, theta, window,
+                     alpha, conf, radio, pidx=None):
+        return _scan_one(rows, cap, rem0, tc, ts, ccum, nf, sr, theta,
+                         window, alpha, conf, radio, adaptive, parametric,
+                         stochastic, backend, chunk, enable_fast, has_burn,
+                         has_send, plan_idx=pidx)
+
     in_axes = ((None if shared_rows else 0), 0, 0, 0, 0, 0, 0, 0, None,
                None, None, 0, None)
-    return jax.vmap(
-        lambda rows, cap, rem0, tc, ts, ccum, nf, sr, theta, window,
-        alpha, conf, radio:
-        _scan_one(rows, cap, rem0, tc, ts, ccum, nf, sr, theta, window,
-                  alpha, conf, radio, adaptive, parametric, stochastic,
-                  backend, chunk, enable_fast, has_burn, has_send),
-        in_axes=in_axes)
+    if shared_rows == "plan":
+        in_axes += (0,)
+    return jax.vmap(fleet_replay, in_axes=in_axes)
 
 
 @lru_cache(maxsize=None)
@@ -1012,8 +1006,13 @@ def _jit_sharded_replay(mesh, shared_rows, adaptive: bool,
         in_specs += (lane,)
     # check_vma=False: lanes never communicate here, so there is no
     # cross-shard value whose replication the type check could verify.
-    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=lane, check_vma=False))
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                            out_specs=lane, check_vma=False)
+
+    def fleet_replay_sharded(*args):
+        return sharded(*args)
+
+    return jax.jit(fleet_replay_sharded)
 
 
 #: Measured event-chunk winners, keyed by (plan bucket shape x replay
@@ -1260,7 +1259,14 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
                 edges: dict | None = None, n_groups: int = 1,
                 plan_idx: np.ndarray | None = None,
                 conf: np.ndarray | None = None, radio=None,
-                config_out: dict | None = None) -> dict | tuple:
+                config_out: dict | None = None,
+                chunk_index: int = 0) -> dict | tuple:
+    """One replay call, from host inputs to host outputs (or their
+    ``FleetStats`` under ``reduce="stats"``).  ``chunk_index`` tags the
+    call's profiler spans with the lane chunk it replays."""
+    import jax
+    from jax.profiler import TraceAnnotation
+
     from repro.runtime.failures import (charge_trace_nominal_from,
                                         pad_charge_trace_columns)
     from repro.runtime.radio import N_RADIO, radio_vector
@@ -1314,19 +1320,47 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
                    else rows["kind"].shape[s_axis], np.int32), (n_lanes,))
     enable_fast = has_burn = False
     nominal_from = np.zeros(n_lanes, np.float64)
-    if stochastic:
-        # Shape-bucket the plan so similarly-sized plans (and different
-        # trace lengths) share one compiled fused replay.
-        has_burn = bool(np.any(rows["kind"] == KIND_BURN))
-        rows = _bucket_rows(rows, lane_axis=lane_axis)
-        if charge_cum is not None:
-            charge_cum = pad_charge_trace_columns(charge_cum, caps)
-            nominal_from = charge_trace_nominal_from(charge_cum, caps)
-            enable_fast = bool(np.any(
-                _reboot_upper_bound(rows, caps, lane_axis)
-                >= nominal_from))
-        else:
-            enable_fast = True
+    if backend == "pallas" and mesh is not None:
+        raise ValueError("backend='pallas' does not compose with mesh "
+                         "sharding; use backend='xla' (or 'auto')")
+    with TraceAnnotation("fleet.prep", chunk=chunk_index):
+        if stochastic:
+            # Shape-bucket the plan so similarly-sized plans (and
+            # different trace lengths) share one compiled fused replay.
+            has_burn = bool(np.any(rows["kind"] == KIND_BURN))
+            rows = _bucket_rows(rows, lane_axis=lane_axis)
+            if charge_cum is not None:
+                charge_cum = pad_charge_trace_columns(charge_cum, caps)
+                nominal_from = charge_trace_nominal_from(charge_cum, caps)
+                enable_fast = bool(np.any(
+                    _reboot_upper_bound(rows, caps, lane_axis)
+                    >= nominal_from))
+            else:
+                enable_fast = True
+        if trace_cum is None:
+            trace_cum = np.zeros((n_lanes, 1), np.float64)
+        if charge_cum is None:
+            charge_cum = np.zeros((n_lanes, 1), np.float64)
+        if tail_s is None:
+            tail_s = np.zeros(n_lanes, np.float64)
+        tail_s = np.broadcast_to(np.asarray(tail_s, np.float64),
+                                 (n_lanes,))
+        with _x64():
+            import jax.numpy as jnp
+            args = [{k: jnp.asarray(v) for k, v in rows.items()},
+                    jnp.asarray(caps), jnp.asarray(rem0),
+                    jnp.asarray(trace_cum), jnp.asarray(tail_s),
+                    jnp.asarray(charge_cum),
+                    jnp.asarray(nominal_from),
+                    jnp.asarray(s_real),
+                    jnp.asarray(float(theta), jnp.float64),
+                    jnp.asarray(float(batch_rows), jnp.float64),
+                    jnp.asarray(float(belief_alpha), jnp.float64),
+                    jnp.asarray(np.broadcast_to(
+                        np.asarray(conf, np.float64), (n_lanes,))),
+                    jnp.asarray(radio_vec)]
+            if plan_mode:
+                args.append(jnp.asarray(np.asarray(plan_idx, np.int32)))
     autotune = chunk == "auto"
     if chunk is None or autotune:
         # Plan-shape-derived event-chunk default: size the inner scan to
@@ -1352,32 +1386,7 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
             backend="xla" if backend == "pallas" else backend,
             chunk=chunk, enable_fast=enable_fast, has_burn=has_burn,
             has_send=has_send)
-    if trace_cum is None:
-        trace_cum = np.zeros((n_lanes, 1), np.float64)
-    if charge_cum is None:
-        charge_cum = np.zeros((n_lanes, 1), np.float64)
-    if tail_s is None:
-        tail_s = np.zeros(n_lanes, np.float64)
-    tail_s = np.broadcast_to(np.asarray(tail_s, np.float64), (n_lanes,))
-    if backend == "pallas" and mesh is not None:
-        raise ValueError("backend='pallas' does not compose with mesh "
-                         "sharding; use backend='xla' (or 'auto')")
     with _x64():
-        import jax.numpy as jnp
-        args = [{k: jnp.asarray(v) for k, v in rows.items()},
-                jnp.asarray(caps), jnp.asarray(rem0),
-                jnp.asarray(trace_cum), jnp.asarray(tail_s),
-                jnp.asarray(charge_cum),
-                jnp.asarray(nominal_from),
-                jnp.asarray(s_real),
-                jnp.asarray(float(theta), jnp.float64),
-                jnp.asarray(float(batch_rows), jnp.float64),
-                jnp.asarray(float(belief_alpha), jnp.float64),
-                jnp.asarray(np.broadcast_to(
-                    np.asarray(conf, np.float64), (n_lanes,))),
-                jnp.asarray(radio_vec)]
-        if plan_mode:
-            args.append(jnp.asarray(np.asarray(plan_idx, np.int32)))
         if autotune:
             chunk = _autotune_event_chunk(
                 (shared_rows, adaptive, parametric, stochastic, backend,
@@ -1402,22 +1411,31 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
                                  has_burn=has_burn, has_send=has_send,
                                  chunk=chunk)
         elif mesh is None:
-            res = _jit_replay(shared_rows, adaptive, parametric,
-                              stochastic, xla_backend, chunk,
-                              enable_fast, has_burn, has_send)(*args)
-        else:
-            res = _sharded_replay(args, mesh, n_lanes, plan_mode,
-                                  shared_rows, adaptive, parametric,
+            with TraceAnnotation("fleet.dispatch", chunk=chunk_index):
+                res = _jit_replay(shared_rows, adaptive, parametric,
                                   stochastic, xla_backend, chunk,
-                                  enable_fast, has_burn, has_send)
-    out = _lane_results(res, n_lanes, trace_cum, tail_s, has_send)
+                                  enable_fast, has_burn, has_send)(*args)
+        else:
+            with TraceAnnotation("fleet.dispatch", chunk=chunk_index):
+                res = _sharded_replay(args, mesh, n_lanes, plan_mode,
+                                      shared_rows, adaptive, parametric,
+                                      stochastic, xla_backend, chunk,
+                                      enable_fast, has_burn, has_send)
+    jax.block_until_ready(res)
+    keep = (np.ones(n_lanes, bool) if valid is None
+            else np.asarray(valid, bool))
+    shards = 1 if mesh is None else int(mesh.devices.size)
+    counts = _pop_event_counts(res, keep, chunk, shards, chunk_index)
+    out = _lane_results(res, n_lanes, trace_cum, tail_s, has_send,
+                        chunk_index=chunk_index)
     if reduce == "stats":
-        keep = (np.ones(n_lanes, bool) if valid is None
-                else np.asarray(valid, bool))
         gid = (np.zeros(n_lanes, np.int64) if group_id is None
                else np.asarray(group_id))
-        return stats_from_outputs({k: v[keep] for k, v in out.items()},
-                                  edges, gid[keep], n_groups)
+        with TraceAnnotation("fleet.fold", chunk=chunk_index):
+            stats = stats_from_outputs({k: v[keep] for k, v in out.items()},
+                                       edges, gid[keep], n_groups)
+        stats.replay_events, stats.replay_event_slots = counts
+        return stats
     return out
 
 
@@ -1474,18 +1492,44 @@ def _dead_time(reboots, trace_cum, tail_s) -> np.ndarray:
 
 
 def _lane_results(res: dict, m: int, trace_cum, tail_s,
-                  has_send: bool) -> dict:
+                  has_send: bool, chunk_index: int = 0) -> dict:
     """A replay call's per-lane outputs as host arrays, cut to the first
     ``m`` (real) lanes.  Every channel the device returns is a whole
     number of cycles or events, exact in the device's float64; dead time
     in seconds is not, so without SEND rows it is recomputed here
     (:func:`_dead_time`).  With SEND rows the replay's own dead time
-    stands: window waits depend on it mid-replay."""
-    out = {k: np.asarray(v)[:m] for k, v in res.items()}
+    stands: window waits depend on it mid-replay.  The caller has waited
+    for ``res``, so the ``fleet.download`` span times the copy alone."""
+    from jax.profiler import TraceAnnotation
+
+    with TraceAnnotation("fleet.download", chunk=chunk_index):
+        out = {k: np.asarray(v)[:m] for k, v in res.items()}
     if not has_send:
-        out["dead"] = _dead_time(out["reboots"], trace_cum[:m],
-                                 tail_s[:m])
+        with TraceAnnotation("fleet.fold", chunk=chunk_index):
+            out["dead"] = _dead_time(out["reboots"], trace_cum[:m],
+                                     tail_s[:m])
     return out
+
+
+def _pop_event_counts(res: dict, keep: np.ndarray, chunk: int, shards: int,
+                      chunk_index: int) -> tuple[int, int]:
+    """Take the event replay's per-lane ``events`` counter off a replay
+    result, so that only the lane channels go on to the host: returns the
+    lane-events of the ``keep`` lanes (the first ``len(keep)`` of the
+    batch, masked) and the lane-event slots the whole batch ran
+    (:func:`repro.kernels.charge_replay.event_slots`); ``(0, 0)`` where
+    the replay has no event loop."""
+    events = res.pop("events", None)
+    if events is None:
+        return 0, 0
+    from jax.profiler import TraceAnnotation
+
+    from repro.kernels.charge_replay import event_slots
+
+    with TraceAnnotation("fleet.download", chunk=chunk_index):
+        events = np.asarray(events)
+    return (int(events[:keep.shape[0]][keep].sum()),
+            event_slots(events, chunk, shards))
 
 
 def _lane_io_bytes(n_lanes: int, *arrays) -> int:
@@ -1540,7 +1584,14 @@ def _chunked_replay(plan_rows: dict, n_rows, n_lanes: int,
     differential oracle for the pipeline; ``peak_lane_bytes`` reports
     the honest pipeline bound: ``(prefetch + 1)`` chunk buffers plus one
     stats partial.  The mesh and Pallas paths keep their own dispatch
-    (``_run_replay``) and overlap stage 1 only."""
+    (``_run_replay``) and overlap stage 1 only.
+
+    Each stage runs in a ``jax.profiler`` span tagged with its chunk
+    index, inside one ``fleet.sweep`` span: ``fleet.sample``,
+    ``fleet.prep``, ``fleet.queue_wait``, ``fleet.dispatch``,
+    ``fleet.download`` and ``fleet.fold``."""
+    from jax.profiler import TraceAnnotation
+
     if lane_chunk < 1:
         raise ValueError(f"lane_chunk must be >= 1, got {lane_chunk}")
     if prefetch < 0:
@@ -1561,15 +1612,18 @@ def _chunked_replay(plan_rows: dict, n_rows, n_lanes: int,
     def build(lo):
         """Pipeline stage 1a (host): one chunk's numpy inputs -- sampler
         draws, grouping, inert-lane padding."""
+        i = lo // lane_chunk
         m = min(lane_chunk, n_lanes - lo)
         pad = lane_chunk - m if n_lanes > lane_chunk else 0
-        caps, rem0, tail, cum, ccum = make_inputs(lo, m)
-        gid = np.asarray(group_id_of(lo, m), np.int32)
-        cnf = (np.asarray(conf_of(lo, m), np.float64)
-               if conf_of is not None else None)
-        pidx = nr = rows_c = None
+        with TraceAnnotation("fleet.sample", chunk=i):
+            caps, rem0, tail, cum, ccum = make_inputs(lo, m)
+            gid = np.asarray(group_id_of(lo, m), np.int32)
+            cnf = (np.asarray(conf_of(lo, m), np.float64)
+                   if conf_of is not None else None)
+            pidx = (np.asarray(plan_idx_of(lo, m), np.int32)
+                    if plan_mode else None)
+        nr = rows_c = None
         if plan_mode:
-            pidx = np.asarray(plan_idx_of(lo, m), np.int32)
             nr = np.asarray(n_rows, np.int32)[pidx]
         elif per_lane_rows:
             rows_c = {k: np.asarray(v)[lo:lo + m]
@@ -1600,9 +1654,9 @@ def _chunked_replay(plan_rows: dict, n_rows, n_lanes: int,
                 rows_c = {k: _pad_axis0(v, pad)
                           for k, v in rows_c.items()}
         valid = np.arange(m + pad) < m
-        return dict(lo=lo, m=m, pad=pad, caps=caps, rem0=rem0, tail=tail,
-                    cum=cum, ccum=ccum, gid=gid, pidx=pidx, nr=nr,
-                    rows=rows_c, valid=valid, conf=cnf)
+        return dict(i=i, lo=lo, m=m, pad=pad, caps=caps, rem0=rem0,
+                    tail=tail, cum=cum, ccum=ccum, gid=gid, pidx=pidx,
+                    nr=nr, rows=rows_c, valid=valid, conf=cnf)
 
     def chunk_bytes(c):
         extra = (tuple(c["rows"].values()) + (c["nr"],)
@@ -1625,12 +1679,18 @@ def _chunked_replay(plan_rows: dict, n_rows, n_lanes: int,
             chunk=event_chunk, reduce=reduce, group_id=c["gid"],
             valid=c["valid"], edges=edges, n_groups=n_groups,
             plan_idx=c["pidx"], conf=c["conf"], radio=radio,
-            config_out=config_out)
+            config_out=config_out, chunk_index=c["i"])
 
-    if prefetch == 0 or len(starts) == 1:
+    with TraceAnnotation("fleet.sweep", lanes=n_lanes, chunks=len(starts)):
+        if prefetch and len(starts) > 1:
+            return _overlapped_replay(
+                plan_rows, n_rows, lane_chunk, starts, build, chunk_bytes,
+                run_chunk, shared_rows, policy, theta, batch_rows,
+                belief_alpha, mesh, backend, reduce, edges, n_groups,
+                event_chunk, config_out, prefetch, radio)
         # -- the legacy fully synchronous loop: generate, replay, fold,
         # repeat.  Kept verbatim as the bit-compat differential oracle
-        # for the overlapped pipeline below.
+        # for the overlapped pipeline.
         acc_stats = None
         outs: list[dict] = []
         peak = 0
@@ -1639,8 +1699,9 @@ def _chunked_replay(plan_rows: dict, n_rows, n_lanes: int,
             peak = max(peak, chunk_bytes(c))
             res = run_chunk(c)
             if stats:
-                acc_stats = res if acc_stats is None \
-                    else acc_stats.merge(res)
+                with TraceAnnotation("fleet.fold", chunk=c["i"]):
+                    acc_stats = res if acc_stats is None \
+                        else acc_stats.merge(res)
             else:
                 outs.append({k: v[:c["m"]] for k, v in res.items()})
         if stats:
@@ -1648,11 +1709,6 @@ def _chunked_replay(plan_rows: dict, n_rows, n_lanes: int,
             return acc_stats
         return {k: np.concatenate([o[k] for o in outs])
                 for k in outs[0]}, peak
-    return _overlapped_replay(plan_rows, n_rows, lane_chunk, starts,
-                              build, chunk_bytes, run_chunk, shared_rows,
-                              policy, theta, batch_rows, belief_alpha,
-                              mesh, backend, reduce, edges, n_groups,
-                              event_chunk, config_out, prefetch, radio)
 
 
 def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
@@ -1670,6 +1726,9 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
     contract; results are bit-exact against ``prefetch=0``."""
     import queue as queue_mod
     import threading
+
+    import jax
+    from jax.profiler import TraceAnnotation
 
     from repro.kernels.charge_replay import (EVENT_CHUNK,
                                              default_event_chunk)
@@ -1743,55 +1802,59 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
             """Pipeline stage 1b (producer thread): stochastic trace
             post-processing + non-blocking device upload of one built
             chunk."""
-            L = c["m"] + c["pad"]
-            caps, rem0, ccum = c["caps"], c["rem0"], c["ccum"]
-            rows_c = c["rows"]
-            nominal_from = np.zeros(L, np.float64)
-            enable_fast = False
-            if stochastic:
-                rem0 = np.where(np.isinf(rem0), np.inf,
-                                np.floor(np.asarray(rem0, np.float64)))
-                if per_lane_rows:
-                    rows_c = _bucket_rows(rows_c, lane_axis=True)
-                if ccum is not None:
-                    ccum = pad_charge_trace_columns(ccum, caps)
-                    nominal_from = charge_trace_nominal_from(ccum, caps)
-                    enable_fast = bool(np.any(_reboot_upper_bound(
-                        rows_c if per_lane_rows else rows_h, caps,
-                        lane_axis) >= nominal_from))
-                else:
-                    enable_fast = True
-            cum = c["cum"]
-            if cum is None:
-                cum = np.zeros((L, 1), np.float64)
-            if ccum is None:
-                ccum = np.zeros((L, 1), np.float64)
-            tail = np.broadcast_to(
-                np.asarray(c["tail"], np.float64), (L,))
-            sr = (np.asarray(c["nr"], np.int32)
-                  if plan_mode or per_lane_rows
-                  else np.broadcast_to(np.asarray(n_rows, np.int32),
-                                       (L,)))
-            cnf = (np.zeros(L, np.float64) if c["conf"] is None
-                   else np.asarray(c["conf"], np.float64))
-            with _x64():
-                args = [(jrows if not per_lane_rows else
-                         {k: jnp.asarray(v) for k, v in rows_c.items()}),
-                        jnp.asarray(caps), jnp.asarray(rem0),
-                        jnp.asarray(cum), jnp.asarray(tail),
-                        jnp.asarray(ccum), jnp.asarray(nominal_from),
-                        jnp.asarray(sr), jtheta, jwindow, jalpha,
-                        jnp.asarray(cnf), jradio]
-                if plan_mode:
-                    args.append(jnp.asarray(
-                        np.asarray(c["pidx"], np.int32)))
-            return c, enable_fast, args, cum, tail
+            with TraceAnnotation("fleet.prep", chunk=c["i"]):
+                L = c["m"] + c["pad"]
+                caps, rem0, ccum = c["caps"], c["rem0"], c["ccum"]
+                rows_c = c["rows"]
+                nominal_from = np.zeros(L, np.float64)
+                enable_fast = False
+                if stochastic:
+                    rem0 = np.where(np.isinf(rem0), np.inf, np.floor(
+                        np.asarray(rem0, np.float64)))
+                    if per_lane_rows:
+                        rows_c = _bucket_rows(rows_c, lane_axis=True)
+                    if ccum is not None:
+                        ccum = pad_charge_trace_columns(ccum, caps)
+                        nominal_from = charge_trace_nominal_from(ccum,
+                                                                 caps)
+                        enable_fast = bool(np.any(_reboot_upper_bound(
+                            rows_c if per_lane_rows else rows_h, caps,
+                            lane_axis) >= nominal_from))
+                    else:
+                        enable_fast = True
+                cum = c["cum"]
+                if cum is None:
+                    cum = np.zeros((L, 1), np.float64)
+                if ccum is None:
+                    ccum = np.zeros((L, 1), np.float64)
+                tail = np.broadcast_to(
+                    np.asarray(c["tail"], np.float64), (L,))
+                sr = (np.asarray(c["nr"], np.int32)
+                      if plan_mode or per_lane_rows
+                      else np.broadcast_to(np.asarray(n_rows, np.int32),
+                                           (L,)))
+                cnf = (np.zeros(L, np.float64) if c["conf"] is None
+                       else np.asarray(c["conf"], np.float64))
+                with _x64():
+                    args = [(jrows if not per_lane_rows else
+                             {k: jnp.asarray(v)
+                              for k, v in rows_c.items()}),
+                            jnp.asarray(caps), jnp.asarray(rem0),
+                            jnp.asarray(cum), jnp.asarray(tail),
+                            jnp.asarray(ccum), jnp.asarray(nominal_from),
+                            jnp.asarray(sr), jtheta, jwindow, jalpha,
+                            jnp.asarray(cnf), jradio]
+                    if plan_mode:
+                        args.append(jnp.asarray(
+                            np.asarray(c["pidx"], np.int32)))
+                return c, enable_fast, args, cum, tail
 
         def dispatch(item, ec):  # noqa: F811
-            _, enable_fast, args, _, _ = item
-            return _jit_replay(shared_rows, adaptive, parametric,
-                               stochastic, xla_backend, ec, enable_fast,
-                               has_burn, has_send)(*args)
+            c, enable_fast, args, _, _ = item
+            with TraceAnnotation("fleet.dispatch", chunk=c["i"]):
+                return _jit_replay(shared_rows, adaptive, parametric,
+                                   stochastic, xla_backend, ec,
+                                   enable_fast, has_burn, has_send)(*args)
 
     tokens.acquire()                        # the first chunk's slot
     item0 = prep(first)
@@ -1833,24 +1896,37 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
         """Fold one chunk's results: stats merge left in chunk order."""
         nonlocal acc
         if stats:
-            acc = res if acc is None else acc.merge(res)
+            with TraceAnnotation("fleet.fold", chunk=c["i"]):
+                acc = res if acc is None else acc.merge(res)
         else:
             outs.append({k: v[:c["m"]] for k, v in res.items()})
 
     def finish(item, res):
         c, _, _, cum, tail = item
-        out = _lane_results(res, c["m"], cum, tail, has_send)
-        keep(c, stats_from_outputs(out, edges, c["gid"][:c["m"]], n_groups)
-             if stats else out)
+        jax.block_until_ready(res)
+        counts = _pop_event_counts(res, c["valid"], echunk, 1, c["i"])
+        out = _lane_results(res, c["m"], cum, tail, has_send,
+                            chunk_index=c["i"])
+        if stats:
+            with TraceAnnotation("fleet.fold", chunk=c["i"]):
+                out = stats_from_outputs(out, edges, c["gid"][:c["m"]],
+                                         n_groups)
+            out.replay_events, out.replay_event_slots = counts
+        keep(c, out)
+
+    def next_chunk(i):
+        with TraceAnnotation("fleet.queue_wait", chunk=i):
+            item = q.get()
+        if isinstance(item, BaseException):
+            raise item
+        return item
 
     try:
         if fast:
             with _x64():
                 held = None                 # (item, result) still replaying
                 for i in range(len(starts)):
-                    item = item0 if i == 0 else q.get()
-                    if isinstance(item, BaseException):
-                        raise item
+                    item = item0 if i == 0 else next_chunk(i)
                     peak_chunk = max(peak_chunk, chunk_bytes(item[0]))
                     res = dispatch(item, echunk)
                     if held is not None:
@@ -1862,9 +1938,7 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
                 tokens.release()
         else:
             for i in range(len(starts)):
-                c = item0 if i == 0 else q.get()
-                if isinstance(c, BaseException):
-                    raise c
+                c = item0 if i == 0 else next_chunk(i)
                 peak_chunk = max(peak_chunk, chunk_bytes(c))
                 keep(c, run_chunk(c))
                 tokens.release()

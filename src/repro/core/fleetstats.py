@@ -171,6 +171,11 @@ class FleetStats:
     group_labels: np.ndarray | None = None   # e.g. capacitor sizes (G,)
     wall_s: float = 0.0               # accumulated replay wall clock
     peak_lane_bytes: int = 0          # max per-chunk lane-buffer bytes
+    # the event replay's active lane-events, and the lane-event slots its
+    # batched loop ran (kernels.charge_replay.event_slots); 0 on the
+    # paths that have no event loop
+    replay_events: int = 0
+    replay_event_slots: int = 0
 
     # -- associative accumulation ----------------------------------------
     def merge(self, other: "FleetStats") -> "FleetStats":
@@ -198,7 +203,10 @@ class FleetStats:
             class_sums=self.class_sums + other.class_sums,
             wall_s=self.wall_s + other.wall_s,
             peak_lane_bytes=max(self.peak_lane_bytes,
-                                other.peak_lane_bytes))
+                                other.peak_lane_bytes),
+            replay_events=self.replay_events + other.replay_events,
+            replay_event_slots=(self.replay_event_slots
+                                + other.replay_event_slots))
 
     # -- queries ---------------------------------------------------------
     @property
@@ -285,6 +293,8 @@ class FleetStats:
             "tx_joules": float(self.sums["tx_joules"][g]),
             "wall_s": round(self.wall_s, 3),
             "peak_lane_bytes": int(self.peak_lane_bytes),
+            "replay_events": int(self.replay_events),
+            "replay_event_slots": int(self.replay_event_slots),
         }
 
 

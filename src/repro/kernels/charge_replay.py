@@ -61,6 +61,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.core.fleetsim import (KIND_BURN, KIND_CALIB, KIND_SEND,
@@ -97,8 +98,9 @@ def default_event_chunk(plan_rows: int) -> int:
     bucketed row count clamped to ``[64, 512]``: row tables are already
     power-of-two bucket-padded (``fleetsim._bucket_rows``), so every plan
     in a bucket derives the same chunk and keeps sharing one compiled
-    replay.  ``benchmarks/fleet.py`` records the derived chunk per
-    strategy (schema 6 ``design_space.event_chunks``)."""
+    replay.  What the overshoot and a batch's slowest lane cost shows in
+    ``FleetStats.replay_events`` over ``replay_event_slots``
+    (:func:`event_slots`)."""
     if plan_rows < 1:
         raise ValueError(f"plan_rows must be >= 1, got {plan_rows}")
     return int(min(_MAX_EVENT_CHUNK,
@@ -649,6 +651,7 @@ class EventState(NamedTuple):
     tx_bytes: jax.Array     # uplink bytes shipped (decision 5)
     sent: jax.Array         # uplink transmissions completed
     deferred: jax.Array     # sends deferred past a closed window
+    events: jax.Array       # events in which the lane was active (int32)
 
 
 def _select(pred, a, b):
@@ -816,7 +819,8 @@ def event_step(packed, layout, cap, trace_cum, tail_s, charge_cum,
         pend=out.pend, pend_class=out.pend_class,
         pend_rows=out.pend_rows, bhat=out.bhat, chg=out.chg,
         debt=out.debt, debt_class=out.debt_class, stuck=out.stuck,
-        tx_bytes=tx_bytes, sent=sent, deferred=deferred)
+        tx_bytes=tx_bytes, sent=sent, deferred=deferred,
+        events=st.events + active.astype(jnp.int32))
 
 
 def event_replay(rows, cap, rem0, trace_cum, tail_s, charge_cum,
@@ -830,6 +834,8 @@ def event_replay(rows, cap, rem0, trace_cum, tail_s, charge_cum,
     ``s_real`` is the lane's real (pre-padding) row count: the cursor
     never walks padding rows, and once ``i == s_real`` every further event
     is a bitwise no-op (see the module docstring's masking scheme).
+    Beside the lane's channels it returns ``events``, the events in which
+    the lane was active (int32; :func:`event_slots`).
 
     Plan IR v2: with stacked ``(P, S, ...)`` rows and a per-lane
     ``plan_idx``, every event reads the lane's own candidate's row from
@@ -851,7 +857,8 @@ def event_replay(rows, cap, rem0, trace_cum, tail_s, charge_cum,
         pend_rows=zero, bhat=cap + zero, chg=zero, debt=zero,
         debt_class=jnp.zeros((_N_CLASSES,), rem0.dtype),
         stuck=jnp.asarray(False),
-        tx_bytes=zero, sent=zero, deferred=zero)
+        tx_bytes=zero, sent=zero, deferred=zero,
+        events=jnp.asarray(0, jnp.int32))
 
     def masked_event(st, _):
         return event_step(packed, layout, cap, trace_cum, tail_s,
@@ -868,7 +875,21 @@ def event_replay(rows, cap, rem0, trace_cum, tail_s, charge_cum,
                 classes=st.classes, wasted=st.wasted, stuck=st.stuck,
                 rem=st.rem, belief=st.bhat,
                 tx_bytes=st.tx_bytes, msgs_sent=st.sent,
-                msgs_deferred=st.deferred)
+                msgs_deferred=st.deferred, events=st.events)
+
+
+def event_slots(events, chunk: int, shards: int = 1) -> int:
+    """Lane-event slots that the batched loop of :func:`event_replay`
+    executes for a vmapped batch whose lanes were active in ``events``
+    events each: every shard of the batch (its contiguous ``1/shards`` of
+    the lanes) runs the outer ``while_loop`` until its slowest lane is
+    done, ``chunk`` events a trip for every lane of the shard, so a shard
+    costs its lanes x ``chunk`` x its largest ``ceil(events / chunk)``.
+    ``sum(events)`` over this is the share of the loop's work that was
+    not masked."""
+    ev = np.asarray(events, np.int64).reshape(shards, -1)
+    trips = -(-ev.max(axis=1, initial=0) // chunk)
+    return int(ev.shape[1] * chunk * trips.sum())
 
 
 # ==========================================================================

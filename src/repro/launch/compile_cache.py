@@ -1,8 +1,7 @@
 """JAX's persistent compilation cache, placed for the entry points.
 
 Entry points (``chip_smoke.py``, ``benchmarks/run.py``,
-``benchmarks/fleet.py``, ``repro.launch.serve``, ``repro.launch.train``)
-call :func:`enable_compile_cache` from ``main``; importing this module
+``repro.launch.serve``, ``repro.launch.train``) call :func:`enable_compile_cache` from ``main``; importing this module
 changes nothing.
 """
 
