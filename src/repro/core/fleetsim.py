@@ -1182,6 +1182,134 @@ def _reboot_upper_bound(rows: dict, caps: np.ndarray,
     return est + burns
 
 
+#: The integer state's infinite capacity (continuous power, and the chunk
+#: pipeline's inert pad lanes): far above any sum a replay reaches, and
+#: mapped back to ``inf`` on the host (:func:`_lane_results`).
+_INT_INF = 1 << 62
+#: Finite quantities the integer state takes: whole numbers below 2**53
+#: in magnitude, so that float64 holds each of them exactly too.
+_INT_LIMIT = 1 << 53
+#: The row bounds of the integer state's division
+#: (``charge_replay.floor_div``): iterations a row, cycles an iteration.
+_INT_MAX_ITERS, _INT_MAX_ITER_CYCLES = 1 << 21, 1 << 30
+
+
+class _ChunkPrep(NamedTuple):
+    """One chunk's stochastic lane operands, in the replay's state."""
+    caps: np.ndarray
+    rem0: np.ndarray
+    charge_cum: np.ndarray
+    nominal_from: np.ndarray
+    enable_fast: bool
+    state: np.dtype
+
+
+def _whole(a) -> bool:
+    """Is every entry of ``a`` a whole number below ``_INT_LIMIT`` in
+    magnitude, or ``+inf``?"""
+    a = np.asarray(a, np.float64)
+    return bool(np.array_equal(np.floor(a), a)         # NaN is not
+                and -_INT_LIMIT < a.min(initial=0)
+                and np.max(a, where=a < np.inf, initial=0) < _INT_LIMIT)
+
+
+def _int_rows_fit(rows: dict) -> bool:
+    """Can the integer state replay these rows: every field whole, and
+    every row within :func:`charge_replay.floor_div`'s bounds?"""
+    if not all(_whole(v) for v in rows.values()):
+        return False
+    iters = [rows["n"]] + ([rows["tile_n"]] if "tile_n" in rows else [])
+    cycles = [rows["iter_cycles"]] + (
+        [rows["tile_iter_cycles"]] if "tile_iter_cycles" in rows else [])
+    return (max(np.abs(v).max(initial=0) for v in iters) < _INT_MAX_ITERS
+            and max(np.abs(v).max(initial=0) for v in cycles)
+            < _INT_MAX_ITER_CYCLES)
+
+
+def _as_int(a) -> np.ndarray:
+    """Whole cycles as int64, ``+inf`` as :data:`_INT_INF`."""
+    a = np.asarray(a, np.float64)
+    inf = np.isposinf(a)
+    with np.errstate(invalid="ignore"):
+        out = a.astype(np.int64)
+    out[inf] = _INT_INF
+    return out
+
+
+def _rows_as(rows: dict, state: np.dtype) -> dict:
+    """The row table in the replay's state: as it is for float64; for
+    int64, every field int32 where all of them fit (an int32 table
+    replays 8% faster on a v5e than an int64 one), else int64."""
+    if state.kind == "f":
+        return rows
+    ints = {k: np.asarray(v).astype(np.int64) for k, v in rows.items()}
+    fits = all(np.abs(v).max(initial=0) < (1 << 31) for v in ints.values())
+    return {k: v.astype(np.int32) if fits else v for k, v in ints.items()}
+
+
+def _int64_available() -> bool:
+    """Does this thread's JAX, in the replay's 64-bit mode, keep int64?"""
+    import jax
+    with _x64():
+        return jax.dtypes.canonicalize_dtype(np.int64) == np.int64
+
+
+def _stochastic_prep(rows: dict, caps, rem0, charge_cum, lane_axis, *,
+                     fused: bool, adaptive: bool, has_send: bool,
+                     belief_alpha: float) -> _ChunkPrep:
+    """A stochastic replay's per-chunk lane prep, shared by
+    :func:`_run_replay` and the overlapped pipeline's producer: the
+    initial charge floored to whole cycles, the capacity trace
+    pow2-padded and its all-nominal tail found (``nominal_from``),
+    whether the fast path is reachable, and the replay's state.
+
+    The state is int64 where the replay's arithmetic is provably
+    integral: the fused event stream under XLA (``fused``), a fixed
+    policy, no SEND rows, ``belief_alpha == 0``, int64 available, and
+    every finite capacity, initial charge and charge-trace entry, and
+    every field of the (bucketed) ``rows``, a whole number
+    (:func:`_whole`, :func:`_int_rows_fit`).  The adaptive policy's
+    debt-class ratio split, the EWMA update and the radio window division
+    are not integral, so those replays stay float64, as does every other.
+    The int64 operands hold an infinite capacity as :data:`_INT_INF`."""
+    from repro.runtime.failures import (charge_trace_nominal_from,
+                                        pad_charge_trace_columns)
+
+    # Fractional initial charges are floored to whole cycles on the
+    # charge-wise path: every cost and capacity is integral, so this keeps
+    # the entire energy state integral -- the invariant that makes the
+    # fused path's closed-form fast forward (and the charge-wise replay)
+    # grouping-independent, i.e. bitwise identical however the charges
+    # are batched.  The deterministic closed form does not need it and
+    # keeps the caller's fractional charge (it is compared against
+    # cycle-exact scalar simulators).
+    rem0 = np.where(np.isinf(rem0), np.inf,
+                    np.floor(np.asarray(rem0, np.float64)))
+    nominal_from = np.zeros(caps.shape[0], np.float64)
+    enable_fast = whole_trace = True
+    if charge_cum is not None:
+        # the padding adds whole multiples of whole caps to the last
+        # column, so a whole trace stays whole where its last column does
+        whole_trace = _whole(charge_cum)
+        charge_cum = pad_charge_trace_columns(charge_cum, caps)
+        whole_trace = whole_trace and _whole(charge_cum[:, -1])
+        nominal_from = charge_trace_nominal_from(charge_cum, caps)
+        enable_fast = bool(np.any(
+            _reboot_upper_bound(rows, caps, lane_axis) >= nominal_from))
+    else:
+        charge_cum = np.zeros((caps.shape[0], 1), np.float64)
+    exact = (fused and not adaptive and not has_send
+             and belief_alpha == 0 and _whole(caps) and _whole(rem0)
+             and whole_trace and _int_rows_fit(rows)
+             and _int64_available())
+    if not exact:
+        return _ChunkPrep(caps, rem0, charge_cum, nominal_from,
+                          enable_fast, np.dtype(np.float64))
+    return _ChunkPrep(_as_int(caps), _as_int(rem0), _as_int(charge_cum),
+                      nominal_from.astype(np.int64), enable_fast,
+                      np.dtype(np.int64))
+
+
 @dataclass
 class PlanSet:
     """Plan IR v2: a stacked batch of candidate plans -- the design axis.
@@ -1267,8 +1395,6 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
     import jax
     from jax.profiler import TraceAnnotation
 
-    from repro.runtime.failures import (charge_trace_nominal_from,
-                                        pad_charge_trace_columns)
     from repro.runtime.radio import N_RADIO, radio_vector
 
     _validate_replay_knobs(policy, batch_rows, belief_alpha, backend,
@@ -1300,17 +1426,6 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
     # capacity trace: route it through the charge-by-charge path, where a
     # missing trace degenerates to all-nominal refills.
     stochastic = charge_cum is not None or (adaptive and batch_rows > 1)
-    # Fractional initial charges are floored to whole cycles on the
-    # charge-wise path: every cost and capacity is integral, so this keeps
-    # the entire energy state in exact-integer float64 arithmetic -- the
-    # invariant that makes the fused path's closed-form fast forward (and
-    # the charge-wise replay) grouping-independent, i.e. bitwise identical
-    # however the charges are batched.  The deterministic closed form does
-    # not need it and keeps the caller's fractional charge (it is compared
-    # against cycle-exact scalar simulators).
-    if stochastic:
-        rem0 = np.where(np.isinf(rem0), np.inf,
-                        np.floor(np.asarray(rem0, np.float64)))
     # Per-lane real row count: the fused path's cursor bound (padding rows
     # past it are never walked).
     s_axis = 0 if shared_rows is True else 1
@@ -1320,6 +1435,7 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
                    else rows["kind"].shape[s_axis], np.int32), (n_lanes,))
     enable_fast = has_burn = False
     nominal_from = np.zeros(n_lanes, np.float64)
+    state = np.dtype(np.float64)
     if backend == "pallas" and mesh is not None:
         raise ValueError("backend='pallas' does not compose with mesh "
                          "sharding; use backend='xla' (or 'auto')")
@@ -1329,14 +1445,12 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
             # different trace lengths) share one compiled fused replay.
             has_burn = bool(np.any(rows["kind"] == KIND_BURN))
             rows = _bucket_rows(rows, lane_axis=lane_axis)
-            if charge_cum is not None:
-                charge_cum = pad_charge_trace_columns(charge_cum, caps)
-                nominal_from = charge_trace_nominal_from(charge_cum, caps)
-                enable_fast = bool(np.any(
-                    _reboot_upper_bound(rows, caps, lane_axis)
-                    >= nominal_from))
-            else:
-                enable_fast = True
+            caps, rem0, charge_cum, nominal_from, enable_fast, state = \
+                _stochastic_prep(rows, caps, rem0, charge_cum, lane_axis,
+                                 fused=backend == "xla", adaptive=adaptive,
+                                 has_send=has_send,
+                                 belief_alpha=belief_alpha)
+            rows = _rows_as(rows, state)
         if trace_cum is None:
             trace_cum = np.zeros((n_lanes, 1), np.float64)
         if charge_cum is None:
@@ -1385,13 +1499,13 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
             parametric=parametric, stochastic=stochastic,
             backend="xla" if backend == "pallas" else backend,
             chunk=chunk, enable_fast=enable_fast, has_burn=has_burn,
-            has_send=has_send)
+            has_send=has_send, state=state.name)
     with _x64():
         if autotune:
             chunk = _autotune_event_chunk(
                 (shared_rows, adaptive, parametric, stochastic, backend,
                  enable_fast, has_burn, has_send, rows["kind"].shape,
-                 n_lanes), rows["kind"].shape[s_axis],
+                 n_lanes, state.name), rows["kind"].shape[s_axis],
                 lambda c: _jit_replay(shared_rows, adaptive, parametric,
                                       stochastic, backend, c, enable_fast,
                                       has_burn, has_send)(*args))
@@ -1411,12 +1525,14 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
                                  has_burn=has_burn, has_send=has_send,
                                  chunk=chunk)
         elif mesh is None:
-            with TraceAnnotation("fleet.dispatch", chunk=chunk_index):
+            with TraceAnnotation("fleet.dispatch", chunk=chunk_index,
+                                 state=state.name):
                 res = _jit_replay(shared_rows, adaptive, parametric,
                                   stochastic, xla_backend, chunk,
                                   enable_fast, has_burn, has_send)(*args)
         else:
-            with TraceAnnotation("fleet.dispatch", chunk=chunk_index):
+            with TraceAnnotation("fleet.dispatch", chunk=chunk_index,
+                                 state=state.name):
                 res = _sharded_replay(args, mesh, n_lanes, plan_mode,
                                       shared_rows, adaptive, parametric,
                                       stochastic, xla_backend, chunk,
@@ -1452,7 +1568,9 @@ def _sharded_replay(args: list, mesh, n_lanes: int, plan_mode: bool,
         # caps, rem0, trace, tail, charge_cum, nominal_from, s_real
         # lane fills (s_real=0: the fused event stream skips the pad
         # lanes outright)
-        fills = (np.inf, np.inf, 0.0, 0.0, 0.0, 0.0, 0)
+        inf = (_INT_INF if np.issubdtype(args[1].dtype, np.integer)
+               else np.inf)
+        fills = (inf, inf, 0, 0, 0, 0, 0)
         for i, fill in enumerate(fills, start=1):
             args[i] = jnp.concatenate(
                 [args[i], jnp.full((pad,) + args[i].shape[1:], fill,
@@ -1495,15 +1613,25 @@ def _lane_results(res: dict, m: int, trace_cum, tail_s,
                   has_send: bool, chunk_index: int = 0) -> dict:
     """A replay call's per-lane outputs as host arrays, cut to the first
     ``m`` (real) lanes.  Every channel the device returns is a whole
-    number of cycles or events, exact in the device's float64; dead time
-    in seconds is not, so without SEND rows it is recomputed here
-    (:func:`_dead_time`).  With SEND rows the replay's own dead time
-    stands: window waits depend on it mid-replay.  The caller has waited
-    for ``res``, so the ``fleet.download`` span times the copy alone."""
+    number of cycles or events, exact in the device's float64 or int64.
+    The integer state's channels come back as float64, exact below 2**53,
+    with its infinite-capacity sentinel as ``inf`` again (``rem``,
+    ``belief``), so every caller sees the float64 state's types and
+    values.  Dead time in seconds is not whole, so without SEND rows it
+    is recomputed here (:func:`_dead_time`; the integer state returns
+    none).  With SEND rows the replay's own dead time stands: window
+    waits depend on it mid-replay.  The caller has waited for ``res``, so
+    the ``fleet.download`` span times the copy alone."""
     from jax.profiler import TraceAnnotation
 
     with TraceAnnotation("fleet.download", chunk=chunk_index):
         out = {k: np.asarray(v)[:m] for k, v in res.items()}
+    for k, v in out.items():
+        if v.dtype.kind == "i":
+            v = v.astype(np.float64)
+            if k in ("rem", "belief"):
+                v = np.where(v >= _INT_INF // 2, np.inf, v)
+            out[k] = v
     if not has_send:
         with TraceAnnotation("fleet.fold", chunk=chunk_index):
             out["dead"] = _dead_time(out["reboots"], trace_cum[:m],
@@ -1629,9 +1757,13 @@ def _chunked_replay(plan_rows: dict, n_rows, n_lanes: int,
             rows_c = {k: np.asarray(v)[lo:lo + m]
                       for k, v in plan_rows.items()}
             nr = n_rows[lo:lo + m]
+        else:
+            nr = np.full(m, n_rows, np.int32)
         if pad:
-            # inert lanes: continuous power completes every row in one
-            # pass; valid=False masks them out of every statistic.
+            # inert lanes: continuous power, and s_real=0 so the fused
+            # event stream never walks them (a walked one would take a
+            # BURN row's refill from its zero charge trace); valid=False
+            # masks them out of every statistic.
             caps = np.concatenate([caps, np.full(pad, np.inf)])
             rem0 = np.concatenate([rem0, np.full(pad, np.inf)])
             tail = np.concatenate([tail, np.zeros(pad)])
@@ -1675,7 +1807,7 @@ def _chunked_replay(plan_rows: dict, n_rows, n_lanes: int,
             tail_s=c["tail"], policy=policy, theta=theta,
             batch_rows=batch_rows, belief_alpha=belief_alpha,
             charge_cum=c["ccum"], mesh=mesh, backend=backend,
-            n_rows=c["nr"] if (plan_mode or per_lane_rows) else n_rows,
+            n_rows=c["nr"],
             chunk=event_chunk, reduce=reduce, group_id=c["gid"],
             valid=c["valid"], edges=edges, n_groups=n_groups,
             plan_idx=c["pidx"], conf=c["conf"], radio=radio,
@@ -1732,17 +1864,15 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
 
     from repro.kernels.charge_replay import (EVENT_CHUNK,
                                              default_event_chunk)
-    from repro.runtime.failures import (charge_trace_nominal_from,
-                                        pad_charge_trace_columns)
 
     from .fleetstats import partial_nbytes
 
     plan_mode = shared_rows == "plan"
     per_lane_rows = shared_rows is False
     stats = reduce == "stats"
-    # The overlapped dispatch replicates _run_replay's prep so it can run
-    # on the producer thread; mesh and Pallas keep their own dispatch
-    # (stage-1 overlap only).
+    # The overlapped dispatch runs _run_replay's prep (the shared
+    # _stochastic_prep) on the producer thread; mesh and Pallas keep
+    # their own dispatch (stage-1 overlap only).
     fast = mesh is None and backend != "pallas"
     depth = prefetch + 1                    # chunks alive at once
     tokens = threading.Semaphore(depth)
@@ -1790,9 +1920,20 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
         if echunk is None or autotune:
             echunk = (default_event_chunk(s_bucket) if stochastic
                       else EVENT_CHUNK)
+        # the chunk-invariant row table, uploaded once for each state a
+        # chunk replays in
+        jrows: dict = {}
+
+        def rows_on_device(rows_c, state):
+            if per_lane_rows:
+                return {k: jnp.asarray(v)
+                        for k, v in _rows_as(rows_c, state).items()}
+            if state not in jrows:
+                jrows[state] = {k: jnp.asarray(v) for k, v in
+                                _rows_as(rows_h, state).items()}
+            return jrows[state]
+
         with _x64():
-            jrows = (None if per_lane_rows else
-                     {k: jnp.asarray(v) for k, v in rows_h.items()})
             jtheta = jnp.asarray(float(theta), jnp.float64)
             jwindow = jnp.asarray(float(batch_rows), jnp.float64)
             jalpha = jnp.asarray(float(belief_alpha), jnp.float64)
@@ -1808,20 +1949,17 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
                 rows_c = c["rows"]
                 nominal_from = np.zeros(L, np.float64)
                 enable_fast = False
+                state = np.dtype(np.float64)
                 if stochastic:
-                    rem0 = np.where(np.isinf(rem0), np.inf, np.floor(
-                        np.asarray(rem0, np.float64)))
                     if per_lane_rows:
                         rows_c = _bucket_rows(rows_c, lane_axis=True)
-                    if ccum is not None:
-                        ccum = pad_charge_trace_columns(ccum, caps)
-                        nominal_from = charge_trace_nominal_from(ccum,
-                                                                 caps)
-                        enable_fast = bool(np.any(_reboot_upper_bound(
+                    caps, rem0, ccum, nominal_from, enable_fast, state = \
+                        _stochastic_prep(
                             rows_c if per_lane_rows else rows_h, caps,
-                            lane_axis) >= nominal_from))
-                    else:
-                        enable_fast = True
+                            rem0, ccum, lane_axis,
+                            fused=xla_backend == "xla",
+                            adaptive=adaptive, has_send=has_send,
+                            belief_alpha=belief_alpha)
                 cum = c["cum"]
                 if cum is None:
                     cum = np.zeros((L, 1), np.float64)
@@ -1829,16 +1967,11 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
                     ccum = np.zeros((L, 1), np.float64)
                 tail = np.broadcast_to(
                     np.asarray(c["tail"], np.float64), (L,))
-                sr = (np.asarray(c["nr"], np.int32)
-                      if plan_mode or per_lane_rows
-                      else np.broadcast_to(np.asarray(n_rows, np.int32),
-                                           (L,)))
+                sr = np.asarray(c["nr"], np.int32)
                 cnf = (np.zeros(L, np.float64) if c["conf"] is None
                        else np.asarray(c["conf"], np.float64))
                 with _x64():
-                    args = [(jrows if not per_lane_rows else
-                             {k: jnp.asarray(v)
-                              for k, v in rows_c.items()}),
+                    args = [rows_on_device(rows_c, state),
                             jnp.asarray(caps), jnp.asarray(rem0),
                             jnp.asarray(cum), jnp.asarray(tail),
                             jnp.asarray(ccum), jnp.asarray(nominal_from),
@@ -1847,11 +1980,12 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
                     if plan_mode:
                         args.append(jnp.asarray(
                             np.asarray(c["pidx"], np.int32)))
-                return c, enable_fast, args, cum, tail
+                return c, enable_fast, args, cum, tail, state
 
         def dispatch(item, ec):  # noqa: F811
-            c, enable_fast, args, _, _ = item
-            with TraceAnnotation("fleet.dispatch", chunk=c["i"]):
+            c, enable_fast, args, _, _, state = item
+            with TraceAnnotation("fleet.dispatch", chunk=c["i"],
+                                 state=state.name):
                 return _jit_replay(shared_rows, adaptive, parametric,
                                    stochastic, xla_backend, ec,
                                    enable_fast, has_burn, has_send)(*args)
@@ -1863,7 +1997,8 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
             echunk = _autotune_event_chunk(
                 (shared_rows, adaptive, parametric, stochastic,
                  xla_backend, item0[1], has_burn, has_send,
-                 item0[2][0]["kind"].shape, lane_chunk), s_bucket,
+                 item0[2][0]["kind"].shape, lane_chunk, item0[5].name),
+                s_bucket,
                 lambda c: dispatch(item0, c))
     if fast and config_out is not None:
         config_out.update(
@@ -1871,7 +2006,7 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
             parametric=parametric, stochastic=stochastic,
             backend=xla_backend, chunk=echunk,
             enable_fast=item0[1], has_burn=has_burn,
-            has_send=has_send)
+            has_send=has_send, state=item0[5].name)
 
     def producer():
         try:
@@ -1902,7 +2037,7 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
             outs.append({k: v[:c["m"]] for k, v in res.items()})
 
     def finish(item, res):
-        c, _, _, cum, tail = item
+        c, _, _, cum, tail, _ = item
         jax.block_until_ready(res)
         counts = _pop_event_counts(res, c["valid"], echunk, 1, c["i"])
         out = _lane_results(res, c["m"], cum, tail, has_send,

@@ -45,6 +45,19 @@ cannot express) selects between ``fast_forward`` and ``charge_once``
 per event, so a lane crosses from traced charges to the closed form
 mid-row without a control-flow boundary.
 
+State type
+----------
+The body is generic over the state's dtype, which follows ``rem0``.
+float64 serves every replay.  Where the arithmetic is provably integral
+(fixed policy, no EWMA belief, no SEND rows, whole capacities, charges,
+trace entries and row fields: ``fleetsim._stochastic_prep`` decides), the
+dispatch passes int64 instead: exact to 2**63 where a TPU's emulated
+float64 keeps about 48 bits, and cheaper there, as 64-bit integer adds
+and compares take a few 32-bit ops.  Division is :func:`floor_div`, and
+the carries that cannot move in that state (rollback debt, the pending
+window, the EWMA belief, the charge length, the device-side dead time)
+are ``None`` rather than carried.
+
 The Pallas kernel (``pallas_replay``) runs the same ``event_replay`` body
 one lane per grid step (scalar state in registers, the plan broadcast to
 every program).  It runs only in interpret mode, which is how the
@@ -120,15 +133,71 @@ def event_chunk_candidates(plan_rows: int) -> tuple:
         for c in (base // 2, base, base * 2)}))
 
 
+def _exact(x) -> bool:
+    """Is ``x`` in the integer state (whole cycles in int64) rather than
+    the float64 one?  Static: the dtype of the replay's ``rem0``."""
+    return not jnp.issubdtype(x.dtype, jnp.floating)
+
+
+def divisor(c):
+    """A per-iteration cost as the divisor of :func:`floor_div`: the
+    float state divides by at least 1e-30, so a zero cost affords as many
+    iterations as the caller's clip allows; the integer state keeps the
+    zero, which :func:`floor_div` maps to the same clipped result."""
+    return c if _exact(c) else jnp.maximum(c, 1e-30)
+
+
+def floor_div(a, b, hi):
+    """``floor(a / b)`` in the state's arithmetic, for ``b`` from
+    :func:`divisor` and ``hi = max(n, 1)``, ``n`` the row's iterations.
+
+    Float state: ``jnp.floor(a / b)``.  Integer state: exactly
+    ``clip(floor(a / b), -1, hi)``, with ``b == 0`` read as the float
+    state's 1e-30 (``hi`` for ``a > 0``, else ``sign(a)``).  Every
+    quotient is clipped to ``[0, left]`` (``left <= n``) or compared with
+    1, so the clip changes no result; it keeps the numerators of the
+    infinite-capacity sentinel (near 2**62) in range.
+
+    The integer quotient is a float32 estimate, clamped to
+    ``[-2, hi + 2]``, then corrected by one step on the remainder
+    ``a - q * b``.  With ``hi < 2**21`` the estimate is within one of the
+    true floor, so the remainder lies in ``[-b, 2b)`` and, for
+    ``b < 2**30``, is exact in wrapping 32-bit arithmetic (the dispatch
+    checks both bounds).  A clamped estimate is off by more than one only
+    where the clipped answer is ``-1`` or ``hi``, which the correction
+    keeps."""
+    if not _exact(a):
+        return jnp.floor(a / b)
+    f32, i32 = jnp.float32, jnp.int32
+    hi = jnp.asarray(hi).astype(a.dtype)
+    bs = jnp.maximum(b, 1)
+    q = jnp.clip(jnp.floor(a.astype(f32) / bs.astype(f32)), -2,
+                 hi.astype(f32) + 2).astype(i32)
+    b32 = bs.astype(i32)
+    r = a.astype(i32) - q * b32
+    q = q - (r < 0).astype(i32) + (r >= b32).astype(i32)
+    q = jnp.clip(q.astype(a.dtype), -1, hi)
+    return jnp.where(b > 0, q, jnp.where(a > 0, hi, jnp.sign(a)))
+
+
+def ceil_div(a, b, hi):
+    """``ceil(a / b)`` for ``0 <= a`` and ``1 <= b``, in the state's
+    arithmetic (integer state: capped at ``hi``, see
+    :func:`floor_div`)."""
+    if not _exact(a):
+        return jnp.ceil(a / b)
+    return floor_div(a + b - 1, b, hi)
+
+
 def trace_window(cum, r0, r1, fallback):
     """Windowed sum of a per-lane cumulative trace over reboots (r0, r1]:
     gather-subtract inside the trace, ``fallback`` per entry past its end.
     Serves the dead-time trace (fallback = mean recharge) and the
     charge-capacity trace (fallback = nominal capacity)."""
     last = cum.shape[0] - 1
-    i0 = jnp.clip(r0, 0.0, last).astype(jnp.int32)
-    i1 = jnp.clip(r1, 0.0, last).astype(jnp.int32)
-    over = jnp.maximum(r1 - last, 0.0) - jnp.maximum(r0 - last, 0.0)
+    i0 = jnp.clip(r0, 0, last).astype(jnp.int32)
+    i1 = jnp.clip(r1, 0, last).astype(jnp.int32)
+    over = jnp.maximum(r1 - last, 0) - jnp.maximum(r0 - last, 0)
     return cum[i1] - cum[i0] + over * fallback
 
 
@@ -136,10 +205,20 @@ def torn_prefix(entry_class, seg_class, seg_cycles, p):
     """Charge-order attribution of a torn entry prefix: walk the row's
     charge-segment list and book ``clip(p - start, 0, len)`` of each block
     to its own class (what the scalar's per-op ``charge`` does).  Exact for
-    multi-dict rows where one class recurs across blocks."""
+    multi-dict rows where one class recurs across blocks.
+
+    The integer state adds one one-hot column a segment instead of
+    scattering: on a TPU a scatter-add of int64 (two 32-bit words) runs
+    serially, and it held 79% of the integer event's device time."""
     starts = jnp.cumsum(seg_cycles) - seg_cycles
-    amt = jnp.clip(p - starts, 0.0, seg_cycles)
-    return jnp.zeros_like(entry_class).at[seg_class].add(amt)
+    amt = jnp.clip(p - starts, 0, seg_cycles)
+    out = jnp.zeros(entry_class.shape, amt.dtype)
+    if not _exact(amt):
+        return out.at[seg_class].add(amt)
+    cls = jnp.arange(entry_class.shape[-1])
+    for g in range(seg_class.shape[-1]):
+        out = out + jnp.where(seg_class[g] == cls, amt[g], 0)
+    return out
 
 
 def send_message_bytes(conf, radio):
@@ -189,7 +268,7 @@ def send_defer_wait(live, dead, radio):
 
 
 def pack_rows(rows: dict):
-    """Flatten a plan's per-row field dict into one ``(S, F)`` f64 matrix
+    """Flatten a plan's per-row field dict into one ``(S, F)`` matrix
     plus a static unpack layout.
 
     An event used to gather ~19 separate row fields (scalars, class
@@ -197,12 +276,14 @@ def pack_rows(rows: dict):
     the dominant per-event cost on gather-bound plans (sonic, tile-8).
     Packing them column-wise means :func:`unpack_row` reads the entire
     row with a single ``dynamic_slice`` of one contiguous ``(1, F)``
-    stripe.  Everything is stored as f64: every integer field (``kind``,
-    ``tile_flag``, the segment class ids) is a small whole number, exact
-    in f64, and is cast back to its original dtype on unpack -- the
-    round-trip is bitwise lossless, so the packed replay is bit-identical
-    to the unpacked one.  The pack itself is event-loop-invariant (built
-    once per replay, hoisted out of the compiled loop).
+    stripe.  A table with any float field is stored as f64: every integer
+    field (``kind``, ``tile_flag``, the segment class ids) is a small
+    whole number, exact in f64, and is cast back to its original dtype on
+    unpack -- the round-trip is bitwise lossless, so the packed replay is
+    bit-identical to the unpacked one.  An all-integer table (the integer
+    state's, int32 where every field fits) keeps its integer type.  The
+    pack itself is event-loop-invariant (built once per replay, hoisted
+    out of the compiled loop).
 
     Plan IR v2: row dicts with a leading *candidate-plan* axis (every
     field shaped ``(P, S, ...)`` -- a stacked ``fleetsim.PlanSet``) pack
@@ -212,10 +293,12 @@ def pack_rows(rows: dict):
     broadcast operand."""
     keys = tuple(sorted(rows))
     lead = int(jnp.asarray(rows["kind"]).ndim)   # 1 = (S,), 2 = (P, S)
+    vals = [jnp.asarray(rows[k]) for k in keys]
+    dt = (jnp.float64 if any(jnp.issubdtype(v.dtype, jnp.floating)
+                             for v in vals) else jnp.result_type(*vals))
     cols, layout, off = [], [], 0
-    for k in keys:
-        v = jnp.asarray(rows[k])
-        flat = v.reshape(v.shape[:lead] + (-1,)).astype(jnp.float64)
+    for k, v in zip(keys, vals):
+        flat = v.reshape(v.shape[:lead] + (-1,)).astype(dt)
         layout.append((k, off, v.shape[lead:], v.dtype))
         cols.append(flat)
         off += flat.shape[-1]
@@ -297,7 +380,7 @@ def row_ctx(row, cap, theta, adaptive: bool, parametric: bool,
     e, entry_class = row["entry_cycles"], row["entry_class"]
     cc, commit_class = row["commit_cycles"], row["commit_class"]
     seg_cycles = row["entry_seg_cycles"]
-    send_bytes = jnp.asarray(0.0, jnp.float64)
+    send_bytes = jnp.zeros((), cap.dtype)
     if has_send:
         is_send = row["kind"] == KIND_SEND
         send_bytes = jnp.where(is_send, send_message_bytes(conf, radio),
@@ -320,10 +403,10 @@ def row_ctx(row, cap, theta, adaptive: bool, parametric: bool,
         batchr = jnp.asarray(False)
     er = jnp.where(batchr, e + cc, e)
     cr = jnp.where(batchr, c - cc, c)
-    crs = jnp.maximum(cr, 1e-30)
+    crs = divisor(cr)
     iter_vecr = jnp.where(batchr, iter_class - commit_class, iter_class)
-    afford_nom = jnp.floor((cap - er) / crs)
-    row_stuck = jnp.where(has_iters, afford_nom < 1.0, e > cap)
+    afford_nom = floor_div(cap - er, crs, jnp.maximum(n, 1))
+    row_stuck = jnp.where(has_iters, afford_nom < 1, e > cap)
     return RowCtx(row["kind"], n, c, e, cc, iter_class, entry_class,
                   commit_class, row["entry_seg_class"], seg_cycles,
                   er, cr, crs, iter_vecr, batchr,
@@ -358,49 +441,62 @@ def charge_once(ctx: RowCtx, cap, charge_cum, theta, window, alpha,
     Phase 0 replays rollback debt, then the row phase schedules from the
     believed budget and executes against the actual delivery; a death
     without a durable cursor write tears the pending window into debt and
-    updates the EWMA belief from the observed charge length."""
+    updates the EWMA belief from the observed charge length.
+
+    In the integer state the dispatch has proved the policy fixed and the
+    belief static (``belief_alpha == 0``), so no debt, pending window or
+    belief update ever arises: those carries are ``None``, phase 0 and
+    their updates are left out, and the believed per-charge budget is
+    ``cap``."""
+    exact = _exact(s.rem)
 
     def refill_sum(r0, r1):
         return trace_window(charge_cum, r0, r1, cap)
 
     a0 = s.rem                     # actual deliverable this charge
     est0 = s.bel                   # the lane's believed budget
+    hi = jnp.maximum(ctx.n, 1)     # quotients are clipped to `left` <= n
 
-    # ---- phase 0: multi-row rollback replay.  Torn pending work (debt)
-    # is re-executed first, one believed-affordable slice per charge, each
-    # slice sealed by its own cursor commit so a replay never grows the
-    # rollback (it converges even when the charges that tore it stay
-    # short).
-    have_debt = s.debt > 0.0
-    debt_s = jnp.maximum(s.debt, 1e-30)
-    want = jnp.where(have_debt,
-                     jnp.minimum(s.debt,
-                                 jnp.maximum(est0 - ctx.cc, 0.0)), 0.0)
-    dok = have_debt & (want > 0.0) & (a0 >= want + ctx.cc)
-    dfail = have_debt & ~dok
-    # a *partial* repay leaves the cursor still inside the rolled-back
-    # rows: the lane cannot run the current row ahead of its own replay,
-    # so the rest of the charge drains and the next charge continues
-    # repaying.  `dend`: this charge ends inside the replay phase and the
-    # row phase never runs.
-    dpart = dok & ((s.debt - want) > 0.0)
-    dend = dfail | dpart
-    d_exec = jnp.where(dfail, jnp.minimum(want, a0), 0.0)
-    d_spend = jnp.where(dok, want + ctx.cc, 0.0)
-    a1 = a0 - d_spend
-    est1 = jnp.maximum(est0 - d_spend, 0.0)
-    debt1 = jnp.where(dok, s.debt - want, s.debt)
-    dcls1 = jnp.where(dok, s.debt_class * ((s.debt - want) / debt_s),
-                      s.debt_class)
-    d_cls = jnp.where(dok,
-                      s.debt_class * (want / debt_s) + ctx.commit_class,
-                      jnp.zeros_like(ctx.commit_class))
-    # a replay commit is a cursor write: it would also cover any pending
-    # rows (pend is zero whenever debt is nonzero by construction -- a
-    # tear converts the whole window to debt)
-    pnd1 = jnp.where(dok, 0.0, s.pend)
-    pcls1 = jnp.where(dok, jnp.zeros_like(s.pend_class), s.pend_class)
-    prw1 = jnp.where(dok, 0.0, s.pend_rows)
+    if exact:
+        dend = jnp.asarray(False)
+        a1, est1 = a0, jnp.maximum(est0, 0)
+        pnd1 = prw1 = jnp.zeros_like(a0)
+    else:
+        # ---- phase 0: multi-row rollback replay.  Torn pending work
+        # (debt) is re-executed first, one believed-affordable slice per
+        # charge, each slice sealed by its own cursor commit so a replay
+        # never grows the rollback (it converges even when the charges
+        # that tore it stay short).
+        have_debt = s.debt > 0.0
+        debt_s = jnp.maximum(s.debt, 1e-30)
+        want = jnp.where(have_debt,
+                         jnp.minimum(s.debt,
+                                     jnp.maximum(est0 - ctx.cc, 0.0)), 0.0)
+        dok = have_debt & (want > 0.0) & (a0 >= want + ctx.cc)
+        dfail = have_debt & ~dok
+        # a *partial* repay leaves the cursor still inside the rolled-back
+        # rows: the lane cannot run the current row ahead of its own
+        # replay, so the rest of the charge drains and the next charge
+        # continues repaying.  `dend`: this charge ends inside the replay
+        # phase and the row phase never runs.
+        dpart = dok & ((s.debt - want) > 0.0)
+        dend = dfail | dpart
+        d_exec = jnp.where(dfail, jnp.minimum(want, a0), 0.0)
+        d_spend = jnp.where(dok, want + ctx.cc, 0.0)
+        a1 = a0 - d_spend
+        est1 = jnp.maximum(est0 - d_spend, 0.0)
+        debt1 = jnp.where(dok, s.debt - want, s.debt)
+        dcls1 = jnp.where(dok, s.debt_class * ((s.debt - want) / debt_s),
+                          s.debt_class)
+        d_cls = jnp.where(dok,
+                          s.debt_class * (want / debt_s) + ctx.commit_class,
+                          jnp.zeros_like(ctx.commit_class))
+        # a replay commit is a cursor write: it would also cover any
+        # pending rows (pend is zero whenever debt is nonzero by
+        # construction -- a tear converts the whole window to debt)
+        pnd1 = jnp.where(dok, 0.0, s.pend)
+        pcls1 = jnp.where(dok, jnp.zeros_like(s.pend_class), s.pend_class)
+        prw1 = jnp.where(dok, 0.0, s.pend_rows)
 
     # ---- batch decision for this charge: the believed remaining budget
     # (post-replay) against the confidence margin theta * bhat; window > 1
@@ -415,7 +511,7 @@ def charge_once(ctx: RowCtx, cap, charge_cum, theta, window, alpha,
         defer = jnp.asarray(False)
     e_b = jnp.where(batch, ctx.e + ctx.cc, ctx.e)
     c_b = jnp.where(batch, ctx.c - ctx.cc, ctx.c)
-    c_bs = jnp.maximum(c_b, 1e-30)
+    c_bs = divisor(c_b)
     iv = jnp.where(batch, ctx.iter_class - ctx.commit_class,
                    ctx.iter_class)
 
@@ -423,31 +519,31 @@ def charge_once(ctx: RowCtx, cap, charge_cum, theta, window, alpha,
     entered = a1 >= ctx.e
     # chunk the lane schedules from its believed budget
     k_est = jnp.clip(jnp.where(est1 >= e_b,
-                               jnp.floor((est1 - e_b) / c_bs), 0.0),
-                     0.0, s.left)
+                               floor_div(est1 - e_b, c_bs, hi), 0),
+                     0, s.left)
     # a deferred row completion schedules all remaining iterations with no
     # commit; otherwise the commit is reserved at the end
     fin_cost = (ctx.e + s.left * c_b
-                + jnp.where(batch & ~defer, ctx.cc, 0.0))
+                + jnp.where(batch & ~defer, ctx.cc, 0))
     plan_fin = est1 >= fin_cost
     sched_i = jnp.where(batch & plan_fin, s.left, k_est)
     # iterations the actual charge affords (per-iteration commits run
     # until real death; entry first, batched commit last)
     k_act = jnp.clip(jnp.where(entered,
-                               jnp.floor((a1 - e_b) / c_bs), 0.0),
-                     0.0, s.left)
+                               floor_div(a1 - e_b, c_bs, hi), 0),
+                     0, s.left)
     k_exec = jnp.clip(jnp.where(entered,
-                                jnp.floor((a1 - ctx.e) / c_bs), 0.0),
-                      0.0, jnp.where(batch, sched_i, s.left))
+                                floor_div(a1 - ctx.e, c_bs, hi), 0),
+                      0, jnp.where(batch, sched_i, s.left))
     fin = jnp.where(batch, plan_fin & (a1 >= fin_cost),
                     a1 >= ctx.e + s.left * c_b)
     # boundary commit: believed end-of-charge at a row boundary with a
     # pending window and no schedulable chunk -- the lane writes the
     # deferred cursor commit *before* draining forward into the next
     # row's entry.
-    boundary = batch & ~plan_fin & (k_est == 0.0) & (prw1 > 0.0)
+    boundary = batch & ~plan_fin & (k_est == 0) & (prw1 > 0)
     sched_commit = jnp.where(plan_fin, ~defer,
-                             (k_est > 0.0) | (prw1 > 0.0))
+                             (k_est > 0) | (prw1 > 0))
     commit_ok = jnp.where(boundary, a1 >= ctx.cc,
                           a1 >= e_b + sched_i * c_b)
     # did a batched cursor write land before this charge died?
@@ -459,20 +555,20 @@ def charge_once(ctx: RowCtx, cap, charge_cum, theta, window, alpha,
                            jnp.where(land & ~boundary, sched_i, k_exec),
                            k_act)
     prog = jnp.where(batch,
-                     jnp.where(land & ~boundary, sched_i, 0.0),
+                     jnp.where(land & ~boundary, sched_i, 0),
                      k_act)
-    commit_n = jnp.where(land, 1.0, 0.0)
+    commit_n = land.astype(a0.dtype)
 
     # death-path entry burn (the boundary commit spends cc first; a failed
     # boundary commit never reaches the entry at all)
     p_entry = jnp.where(boundary,
-                        jnp.where(land, a1 - ctx.cc, -1.0), a1)
+                        jnp.where(land, a1 - ctx.cc, -1), a1)
     entered_d = p_entry >= ctx.e
     torn_v = jnp.where(entered_d, jnp.zeros_like(ctx.entry_class),
                        torn_prefix(ctx.entry_class, ctx.seg_class,
                                    ctx.seg_cycles, p_entry))
     entry_burn = jnp.where(entered_d, ctx.e,
-                           jnp.clip(p_entry, 0.0, ctx.e))
+                           jnp.clip(p_entry, 0, ctx.e))
     cls_burn = (jnp.where(entered_d, ctx.entry_class,
                           jnp.zeros_like(ctx.entry_class))
                 + torn_v + exec_iters * iv
@@ -481,9 +577,22 @@ def charge_once(ctx: RowCtx, cap, charge_cum, theta, window, alpha,
     cls_death = cls_burn.at[_CONTROL_IDX].add(residue)
     spend_fin = fin_cost
     cls_fin = (ctx.entry_class + s.left * iv
-               + jnp.where(batch & ~defer, 1.0, 0.0) * ctx.commit_class)
+               + (batch & ~defer).astype(a0.dtype) * ctx.commit_class)
 
     fin_ok = fin & ~dend
+    stuck_now = (~fin_ok) & ctx.row_stuck
+    if exact:
+        return s._replace(
+            rem=jnp.where(fin_ok, a1 - spend_fin,
+                          refill_sum(s.reboots, s.reboots + 1)),
+            bel=jnp.where(fin_ok, jnp.maximum(est1 - spend_fin, 0), cap),
+            left=jnp.where(fin_ok, 0, s.left - prog),
+            live=s.live + jnp.where(fin, spend_fin, a1),
+            reboots=s.reboots + jnp.where(fin_ok, 0, 1),
+            classes=s.classes + jnp.where(fin, cls_fin, cls_death),
+            stuck=s.stuck | stuck_now,
+            done=s.done | fin_ok | stuck_now)
+
     # a death without any durable cursor write tears the pending window:
     # those rows roll back and become replay debt
     committed = jnp.where(batch, land, k_act > 0.0)
@@ -514,7 +623,6 @@ def charge_once(ctx: RowCtx, cap, charge_cum, theta, window, alpha,
                                    1.0),
                        s.bhat)
 
-    stuck_now = (~fin_ok) & ctx.row_stuck
     dfail_cls = (s.debt_class * (d_exec / debt_s)
                  ).at[_CONTROL_IDX].add(a0 - d_exec)
     # a partial repay's drained remainder is a chunk-boundary drain
@@ -557,8 +665,10 @@ def fast_forward(ctx: RowCtx, cap, theta, adaptive: bool,
     deterministic path's chunk/retry algebra (this *is* the deterministic
     path -- ``_scan_step`` calls it with a fresh row).  Integral energy
     state makes the grouped arithmetic exact, so the result is
-    bit-identical to iterating :func:`charge_once` over nominal refills."""
+    bit-identical to iterating :func:`charge_once` over nominal refills.
+    The integer state carries no ``chg`` (see :func:`charge_once`)."""
     rem, left = s.rem, s.left
+    hi = jnp.maximum(ctx.n, 1)
     if adaptive:
         lvl0 = jnp.where(jnp.isinf(cap), True, s.bel >= theta * s.bhat)
         batch0 = ctx.has_iters & (ctx.cc > 0.0) & lvl0
@@ -566,7 +676,7 @@ def fast_forward(ctx: RowCtx, cap, theta, adaptive: bool,
         batch0 = jnp.asarray(False)
     e0 = jnp.where(batch0, ctx.e + ctx.cc, ctx.e)
     c0 = jnp.where(batch0, ctx.c - ctx.cc, ctx.c)
-    c0s = jnp.maximum(c0, 1e-30)
+    c0s = divisor(c0)
     iter_vec0 = jnp.where(batch0, ctx.iter_class - ctx.commit_class,
                           ctx.iter_class)
 
@@ -576,24 +686,25 @@ def fast_forward(ctx: RowCtx, cap, theta, adaptive: bool,
     # failure path (finite capacity; never selected when rem == inf)
     entered = rem >= ctx.e
     afford0 = jnp.clip(jnp.where(entered,
-                                 jnp.floor((rem - e0) / c0s), 0.0),
-                       0.0, left)
+                                 floor_div(rem - e0, c0s, hi), 0),
+                       0, left)
     rem_iters = left - afford0
-    afford_full = jnp.maximum(ctx.afford_nom, 1.0)
+    afford_full = jnp.maximum(ctx.afford_nom, 1)
     visits = jnp.where(ctx.has_iters,
-                       jnp.maximum(jnp.ceil(rem_iters / afford_full), 1.0),
-                       1.0)
+                       jnp.maximum(ceil_div(rem_iters, afford_full, hi),
+                                   1),
+                       1)
     n_last = jnp.where(ctx.has_iters,
-                       rem_iters - (visits - 1.0) * afford_full, 0.0)
-    fail_live = rem + (visits - 1.0) * cap + ctx.er + n_last * ctx.cr
+                       rem_iters - (visits - 1) * afford_full, 0)
+    fail_live = rem + (visits - 1) * cap + ctx.er + n_last * ctx.cr
     fail_rem = cap - ctx.er - n_last * ctx.cr
     entries = visits + entered.astype(rem.dtype)
 
     # Batched-commit bookkeeping: one cursor write per visit that executed
     # iterations (+1 if attempt 0 entered and progressed).
-    ok_commits = jnp.where(batch0, 1.0, 0.0)
-    fail_commits = (jnp.where(ctx.batchr, visits, 0.0)
-                    + jnp.where(batch0 & (afford0 > 0), 1.0, 0.0))
+    ok_commits = batch0.astype(rem.dtype)
+    fail_commits = (jnp.where(ctx.batchr, visits, 0)
+                    + (batch0 & (afford0 > 0)).astype(rem.dtype))
 
     fail_classes = (entries * ctx.entry_class + afford0 * iter_vec0
                     + rem_iters * ctx.iter_vecr
@@ -608,27 +719,33 @@ def fast_forward(ctx: RowCtx, cap, theta, adaptive: bool,
     fail_classes = fail_classes + torn
     residue = (fail_live - entries * ctx.e - afford0 * c0
                - rem_iters * ctx.cr - fail_commits * ctx.cc
-               - jnp.where(entered, 0.0, rem))
+               - jnp.where(entered, 0, rem))
     fail_classes = fail_classes.at[_CONTROL_IDX].add(residue)
 
     ok_classes = (ctx.entry_class + left * iter_vec0
                   + ok_commits * ctx.commit_class)
     new_rem = jnp.where(ok, rem - needed, fail_rem)
-    return s._replace(
+    out = s._replace(
         rem=new_rem,
         bel=new_rem,         # nominal charges: belief is exact
         left=jnp.zeros_like(left),
         live=s.live + jnp.where(ok, needed, fail_live),
-        reboots=s.reboots + jnp.where(ok, 0.0, visits),
+        reboots=s.reboots + jnp.where(ok, 0, visits),
         classes=s.classes + jnp.where(ok, ok_classes, fail_classes),
-        chg=jnp.where(ok, s.chg + needed, ctx.er + n_last * ctx.cr),
         stuck=s.stuck | ((~ok) & ctx.row_stuck),
         done=jnp.asarray(True) | s.done)
+    if _exact(rem):
+        return out
+    return out._replace(
+        chg=jnp.where(ok, s.chg + needed, ctx.er + n_last * ctx.cr))
 
 
 class EventState(NamedTuple):
     """Per-lane carry of the flat event stream: the row cursor, the
-    charge-loop state, and the per-row dead-time anchor."""
+    charge-loop state, and the per-row dead-time anchor.  The integer
+    state carries neither the device-side dead time (the host recomputes
+    it from the final reboots) nor what :func:`charge_once` leaves out
+    there: those fields are ``None``."""
     i: jax.Array            # row cursor (int32)
     fresh: jax.Array        # next event starts a new row
     row_r0: jax.Array       # reboot counter at the current row's entry
@@ -677,12 +794,16 @@ def event_step(packed, layout, cap, trace_cum, tail_s, charge_cum,
     BURN override is dead code without BURN rows -- it only removes the
     corresponding per-event arithmetic from the compiled body.  With a
     ``(P, S, F)`` pack (Plan IR v2), ``plan`` is the lane's candidate
-    index into the stacked row table."""
+    index into the stacked row table.  In the integer state the believed
+    per-charge budget is ``cap`` and ``alpha`` is 0 (see
+    :func:`charge_once`)."""
+    exact = _exact(st.rem)
     s_pad = packed.shape[-2]
     i = jnp.minimum(st.i, s_pad - 1)
     row = unpack_row(packed, layout, i, plan)
     ctx = row_ctx(row, cap, theta, adaptive, parametric,
                   conf=conf, radio=radio, has_send=has_send)
+    bhat = cap if exact else st.bhat
 
     # Entering a row resets the row-local loop state (iterations left,
     # rollback debt -- a stuck row's discarded debt must not leak).
@@ -692,8 +813,8 @@ def event_step(packed, layout, cap, trace_cum, tail_s, charge_cum,
     # window sleeps (dead time, no energy) until the window opens.  Only
     # the *first* entry defers; a retry after a torn send transmits as
     # soon as the buffer recharges (documented simplification).
-    send_wait = jnp.zeros_like(st.dead)
     defer_now = jnp.zeros_like(fresh)
+    send_wait = None if exact else jnp.zeros_like(st.dead)
     if has_send:
         is_send = ctx.kind == KIND_SEND
         want_send = fresh & is_send & (ctx.send_bytes > 0.0) \
@@ -708,10 +829,9 @@ def event_step(packed, layout, cap, trace_cum, tail_s, charge_cum,
         live=st.live, reboots=st.reboots, classes=st.classes,
         wasted=st.wasted, pend=st.pend, pend_class=st.pend_class,
         pend_rows=st.pend_rows, bhat=st.bhat, chg=st.chg,
-        debt=jnp.where(fresh, 0.0, st.debt),
-        debt_class=jnp.where(fresh,
-                             jnp.zeros_like(st.debt_class),
-                             st.debt_class),
+        debt=None if exact else jnp.where(fresh, 0.0, st.debt),
+        debt_class=None if exact else jnp.where(
+            fresh, jnp.zeros_like(st.debt_class), st.debt_class),
         stuck=st.stuck, done=jnp.asarray(False))
 
     slow = charge_once(ctx, cap, charge_cum, theta, window, alpha,
@@ -724,13 +844,16 @@ def event_step(packed, layout, cap, trace_cum, tail_s, charge_cum,
         # cannot express (stuck rows stop after one charge; deferral
         # under window > 1 opens the pending window; EWMA updates are
         # no-ops only while observed charges are exactly nominal --
-        # chg + rem == cap -- or before any refill).
+        # chg + rem == cap -- or before any refill).  The integer state
+        # has no cross-charge state and no EWMA.
         elig = ((st.reboots >= nominal_from)
-                & (cs.bel == cs.rem) & (cs.bhat == cap)
-                & (cs.pend == 0.0) & (cs.pend_rows == 0.0)
-                & (cs.debt == 0.0) & ~ctx.row_stuck
-                & ((alpha <= 0.0) | (cs.chg + cs.rem == cap)
-                   | (cs.reboots == 0.0)))
+                & (cs.bel == cs.rem) & ~ctx.row_stuck)
+        if not exact:
+            elig = (elig & (cs.bhat == cap)
+                    & (cs.pend == 0.0) & (cs.pend_rows == 0.0)
+                    & (cs.debt == 0.0)
+                    & ((alpha <= 0.0) | (cs.chg + cs.rem == cap)
+                       | (cs.reboots == 0.0)))
         if adaptive:
             elig = elig & (window <= 1.0)
         fast = fast_forward(ctx, cap, theta, adaptive, cs)
@@ -753,15 +876,17 @@ def event_step(packed, layout, cap, trace_cum, tail_s, charge_cum,
         out = out._replace(
             rem=jnp.where(is_burn,
                           trace_window(charge_cum, st.reboots,
-                                       st.reboots + 1.0, cap), out.rem),
-            bel=jnp.where(is_burn, st.bhat, out.bel),
+                                       st.reboots + 1, cap), out.rem),
+            bel=jnp.where(is_burn, bhat, out.bel),
             live=jnp.where(is_burn, st.live + cs.rem, out.live),
-            reboots=jnp.where(is_burn, st.reboots + 1.0, out.reboots),
+            reboots=jnp.where(is_burn, st.reboots + 1, out.reboots),
             classes=jnp.where(is_burn, st.classes + burn_vec,
                               out.classes),
             stuck=jnp.where(is_burn, st.stuck, out.stuck),
-            wasted=jnp.where(is_burn, st.wasted, out.wasted),
-            chg=jnp.where(is_burn, jnp.zeros_like(out.chg), out.chg))
+            wasted=jnp.where(is_burn, st.wasted, out.wasted))
+        if not exact:
+            out = out._replace(chg=jnp.where(
+                is_burn, jnp.zeros_like(out.chg), out.chg))
 
     # -- CALIB rows: per-lane burn count from the capacitor (Sec. 7.1)
     if parametric:
@@ -770,37 +895,42 @@ def event_step(packed, layout, cap, trace_cum, tail_s, charge_cum,
         calib_live = jnp.where(
             burns > 0,
             cs.rem + trace_window(charge_cum, st.reboots,
-                                  st.reboots + burns - 1.0, cap), 0.0)
+                                  st.reboots + burns - 1, cap), 0)
         calib_rem = jnp.where(
             burns > 0,
-            trace_window(charge_cum, st.reboots + burns - 1.0,
+            trace_window(charge_cum, st.reboots + burns - 1,
                          st.reboots + burns, cap), cs.rem)
         calib_vec = jnp.zeros_like(cs.classes).at[_BURN_IDX].add(
             calib_live)
         out = out._replace(
             rem=jnp.where(is_calib, calib_rem, out.rem),
             bel=jnp.where(is_calib,
-                          jnp.where(burns > 0, st.bhat, cs.bel), out.bel),
+                          jnp.where(burns > 0, bhat, cs.bel), out.bel),
             live=jnp.where(is_calib, st.live + calib_live, out.live),
             reboots=jnp.where(is_calib, st.reboots + burns, out.reboots),
             classes=jnp.where(is_calib, st.classes + calib_vec,
                               out.classes),
             stuck=jnp.where(is_calib, st.stuck, out.stuck),
-            wasted=jnp.where(is_calib, st.wasted, out.wasted),
-            chg=jnp.where(is_calib & (burns > 0),
-                          jnp.zeros_like(out.chg), out.chg))
+            wasted=jnp.where(is_calib, st.wasted, out.wasted))
+        if not exact:
+            out = out._replace(chg=jnp.where(
+                is_calib & (burns > 0), jnp.zeros_like(out.chg), out.chg))
 
     advance = active & jnp.where(is_work, out.done, True)
-    # decision 3: per-reboot dead time, booked once per row from the
-    # reboot counter at the row's entry (the same single gather-subtract
-    # the unfused path evaluates, for bitwise identity).  The window wait
-    # is added first as its own float step so the unfused path (which
-    # books the wait at row entry) stays bitwise identical.
-    dead_base = st.dead + send_wait
-    dead = jnp.where(advance,
-                     dead_base + trace_window(trace_cum, st.row_r0,
-                                              out.reboots, tail_s),
-                     dead_base)
+    row_r0 = dead = None
+    if not exact:
+        # decision 3: per-reboot dead time, booked once per row from the
+        # reboot counter at the row's entry (the same single
+        # gather-subtract the unfused path evaluates, for bitwise
+        # identity).  The window wait is added first as its own float
+        # step so the unfused path (which books the wait at row entry)
+        # stays bitwise identical.
+        dead_base = st.dead + send_wait
+        dead = jnp.where(advance,
+                         dead_base + trace_window(trace_cum, st.row_r0,
+                                                  out.reboots, tail_s),
+                         dead_base)
+        row_r0 = jnp.where(advance, out.reboots, st.row_r0)
     tx_bytes, sent, deferred = st.tx_bytes, st.sent, st.deferred
     if has_send:
         # Book TX on row completion; a stuck SEND row (cost > capacity)
@@ -812,7 +942,7 @@ def event_step(packed, layout, cap, trace_cum, tail_s, charge_cum,
     return EventState(
         i=st.i + advance.astype(jnp.int32),
         fresh=advance,
-        row_r0=jnp.where(advance, out.reboots, st.row_r0),
+        row_r0=row_r0,
         dead=dead,
         rem=out.rem, bel=out.bel, left=out.left, live=out.live,
         reboots=out.reboots, classes=out.classes, wasted=out.wasted,
@@ -837,6 +967,17 @@ def event_replay(rows, cap, rem0, trace_cum, tail_s, charge_cum,
     Beside the lane's channels it returns ``events``, the events in which
     the lane was active (int32; :func:`event_slots`).
 
+    The state's dtype is ``rem0``'s.  float64 serves every replay.  An
+    integer ``rem0`` (int64) selects the integer state, which the
+    dispatch (``fleetsim._stochastic_prep``) gives a replay only where
+    its arithmetic is integral: fixed policy, ``alpha == 0``, no SEND
+    rows, and ``cap``, ``charge_cum``, ``nominal_from`` and every row
+    field whole numbers of the same integer kind, an infinite capacity
+    held as a sentinel far above any reachable sum.  That state carries
+    no debt, pending window, EWMA belief or charge length, none of which
+    can arise, and no device-side dead time: its result has no ``dead``
+    channel, and ``belief`` is ``cap``.
+
     Plan IR v2: with stacked ``(P, S, ...)`` rows and a per-lane
     ``plan_idx``, every event reads the lane's own candidate's row from
     the shared ``(P, S, F)`` pack -- the pack stays a broadcast
@@ -845,17 +986,19 @@ def event_replay(rows, cap, rem0, trace_cum, tail_s, charge_cum,
     packed, layout = pack_rows(rows)
     if plan_idx is not None:
         plan_idx = jnp.asarray(plan_idx, jnp.int32)
+    exact = _exact(rem0)
     zero = jnp.zeros_like(rem0)
+    vec = jnp.zeros((_N_CLASSES,), rem0.dtype)
+    inert = None if exact else zero
     st0 = EventState(
         i=jnp.asarray(0, jnp.int32),
         fresh=jnp.asarray(True),
-        row_r0=zero, dead=zero,
+        row_r0=inert, dead=inert,
         rem=rem0, bel=rem0, left=zero, live=zero, reboots=zero,
-        classes=jnp.zeros((_N_CLASSES,), rem0.dtype),
-        wasted=zero, pend=zero,
-        pend_class=jnp.zeros((_N_CLASSES,), rem0.dtype),
-        pend_rows=zero, bhat=cap + zero, chg=zero, debt=zero,
-        debt_class=jnp.zeros((_N_CLASSES,), rem0.dtype),
+        classes=vec, wasted=zero, pend=inert,
+        pend_class=None if exact else vec,
+        pend_rows=inert, bhat=None if exact else cap + zero, chg=inert,
+        debt=inert, debt_class=None if exact else vec,
         stuck=jnp.asarray(False),
         tx_bytes=zero, sent=zero, deferred=zero,
         events=jnp.asarray(0, jnp.int32))
@@ -871,11 +1014,14 @@ def event_replay(rows, cap, rem0, trace_cum, tail_s, charge_cum,
         lambda st: st.i < s_real,
         lambda st: lax.scan(masked_event, st, None, length=chunk)[0],
         st0)
-    return dict(live=st.live, reboots=st.reboots, dead=st.dead,
-                classes=st.classes, wasted=st.wasted, stuck=st.stuck,
-                rem=st.rem, belief=st.bhat,
-                tx_bytes=st.tx_bytes, msgs_sent=st.sent,
-                msgs_deferred=st.deferred, events=st.events)
+    out = dict(live=st.live, reboots=st.reboots, dead=st.dead,
+               classes=st.classes, wasted=st.wasted, stuck=st.stuck,
+               rem=st.rem, belief=cap + zero if exact else st.bhat,
+               tx_bytes=st.tx_bytes, msgs_sent=st.sent,
+               msgs_deferred=st.deferred, events=st.events)
+    if exact:
+        del out["dead"]
+    return out
 
 
 def event_slots(events, chunk: int, shards: int = 1) -> int:

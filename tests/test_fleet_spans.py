@@ -97,6 +97,9 @@ def _check_spans(spans, queue: bool) -> int:
     names = {s["name"] for s in spans}
     assert names == {"fleet.sweep", *STAGES} | (
         {"fleet.queue_wait"} if queue else set())
+    # the fixed-policy stochastic sweep replays every chunk in int64
+    assert {s["args"].get("state") for s in spans
+            if s["name"] == "fleet.dispatch"} == {"int64"}
     for s in spans:
         assert sweep["start"] <= s["start"] <= s["end"] <= sweep["end"] \
             or s["line"] != sweep["line"], s
@@ -192,7 +195,9 @@ def test_closed_form_counts_nothing():
 def test_the_counter_does_not_reach_the_lane_results(monkeypatch,
                                                      prefetch):
     """``_lane_results`` gets the replay's lane channels, in their types,
-    and never the event counter."""
+    and never the event counter: this fixed-policy stochastic sweep
+    replays in the integer state, so every cycle channel comes as int64
+    and the dead time, which the host recomputes, not at all."""
     real, seen = fleetsim._lane_results, {}
 
     def spy(res, *a, **kw):
@@ -203,9 +208,9 @@ def test_the_counter_does_not_reach_the_lane_results(monkeypatch,
     monkeypatch.setattr(fleetsim, "_lane_results", spy)
     fleet_sweep(plan=build_plan(*_net(), "sonic", "1mF"), prefetch=prefetch,
                 **SWEEP)
-    f64 = {np.dtype(np.float64)}
-    assert seen == {**{k: f64 for k in (
-        "live", "reboots", "dead", "classes", "wasted", "rem", "belief",
+    i64 = {np.dtype(np.int64)}
+    assert seen == {**{k: i64 for k in (
+        "live", "reboots", "classes", "wasted", "rem", "belief",
         "tx_bytes", "msgs_sent", "msgs_deferred")},
         "stuck": {np.dtype(bool)}}
 

@@ -289,14 +289,17 @@ def test_prefetch_validation(small_net):
 
 @pytest.mark.parametrize("prefetch", [0, 1])
 def test_replay_operands_are_float64(small_net, monkeypatch, prefetch):
-    """Every chunk's replay call gets float64 operands, including those
+    """Every chunk's replay call gets 64-bit operands, including those
     the producer thread uploads (64-bit mode is per thread, so the
-    producer enters its own), and never a float32 one."""
+    producer enters its own): every float one float64, never a float32,
+    and every energy-state one (capacities, initial charges, charge
+    trace, all-nominal index) int64 or float64 -- here int64, the
+    fixed-policy stochastic replay's integer state."""
     import jax
 
     import repro.core.fleetsim as fs
 
-    real, dtypes = fs._jit_replay, []
+    real, dtypes, state = fs._jit_replay, [], []
 
     def spy(*key):
         fn = real(*key)
@@ -305,6 +308,7 @@ def test_replay_operands_are_float64(small_net, monkeypatch, prefetch):
             dtypes.append({np.dtype(a.dtype)
                            for a in jax.tree_util.tree_leaves(args)
                            if np.issubdtype(a.dtype, np.floating)})
+            state.append({np.dtype(args[i].dtype) for i in (1, 2, 5, 6)})
             return fn(*args)
         return call
 
@@ -315,3 +319,4 @@ def test_replay_operands_are_float64(small_net, monkeypatch, prefetch):
                 reduce="stats")
     assert len(dtypes) == 3
     assert all(d == {np.dtype(np.float64)} for d in dtypes), dtypes
+    assert state == [{np.dtype(np.int64)}] * 3, state

@@ -59,7 +59,7 @@ class _Captured(Exception):
 
 
 def test_fleet_replay_compiles(one_chip, no_compile_cache, monkeypatch):
-    """The fused replay (``_jit_replay``, float64 state) for the
+    """The fused replay (``_jit_replay``, int64 state) for the
     compressed HAR network's SONIC plan, 256 lanes of a streamed
     ``reduce="stats"`` sweep under the stochastic energy model, fits one
     chip."""
