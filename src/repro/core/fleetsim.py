@@ -1247,6 +1247,33 @@ def _rows_as(rows: dict, state: np.dtype) -> dict:
     return {k: v.astype(np.int32) if fits else v for k, v in ints.items()}
 
 
+def _upload_rows(rows: dict, state: np.dtype) -> dict:
+    """The row table in the replay's state (:func:`_rows_as`), put on the
+    device, inside a ``fleet.rows`` span whose args are the table's row
+    slots and bytes.  Call under :func:`_x64`."""
+    import jax.numpy as jnp
+    from jax.profiler import TraceAnnotation
+
+    with TraceAnnotation("fleet.rows",
+                         rows=int(np.asarray(rows["kind"]).size)) as span:
+        table = _rows_as(rows, state)
+        span.set_metadata(bytes=int(sum(np.asarray(v).nbytes
+                                        for v in table.values())))
+        return {k: jnp.asarray(v) for k, v in table.items()}
+
+
+def _distinct_plans(shared_rows, plan_idx, keep: np.ndarray) -> int:
+    """The candidates among a replay call's ``keep`` lanes: distinct
+    indices on the stacked candidate axis (``shared_rows="plan"``), one a
+    lane for per-lane rows, else 1."""
+    if shared_rows == "plan":
+        return int(np.unique(np.asarray(plan_idx)[:keep.shape[0]][keep])
+                   .size)
+    if shared_rows is False:
+        return int(np.count_nonzero(keep))
+    return 1
+
+
 def _int64_available() -> bool:
     """Does this thread's JAX, in the replay's 64-bit mode, keep int64?"""
     import jax
@@ -1450,7 +1477,6 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
                                  fused=backend == "xla", adaptive=adaptive,
                                  has_send=has_send,
                                  belief_alpha=belief_alpha)
-            rows = _rows_as(rows, state)
         if trace_cum is None:
             trace_cum = np.zeros((n_lanes, 1), np.float64)
         if charge_cum is None:
@@ -1461,7 +1487,7 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
                                  (n_lanes,))
         with _x64():
             import jax.numpy as jnp
-            args = [{k: jnp.asarray(v) for k, v in rows.items()},
+            args = [_upload_rows(rows, state),
                     jnp.asarray(caps), jnp.asarray(rem0),
                     jnp.asarray(trace_cum), jnp.asarray(tail_s),
                     jnp.asarray(charge_cum),
@@ -1512,6 +1538,9 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
             if config_out is not None:
                 config_out["chunk"] = chunk
         xla_backend = "xla" if backend == "pallas" else backend
+        keep = (np.ones(n_lanes, bool) if valid is None
+                else np.asarray(valid, bool))
+        plans = _distinct_plans(shared_rows, plan_idx, keep)
         if backend == "pallas" and stochastic:
             # The Pallas lane kernel (interpret mode only); the
             # deterministic closed form has no charge loop to fuse, so a
@@ -1526,31 +1555,30 @@ def _run_replay(rows: dict, caps: np.ndarray, rem0: np.ndarray,
                                  chunk=chunk)
         elif mesh is None:
             with TraceAnnotation("fleet.dispatch", chunk=chunk_index,
-                                 state=state.name):
+                                 state=state.name, plans=plans):
                 res = _jit_replay(shared_rows, adaptive, parametric,
                                   stochastic, xla_backend, chunk,
                                   enable_fast, has_burn, has_send)(*args)
         else:
             with TraceAnnotation("fleet.dispatch", chunk=chunk_index,
-                                 state=state.name):
+                                 state=state.name, plans=plans):
                 res = _sharded_replay(args, mesh, n_lanes, plan_mode,
                                       shared_rows, adaptive, parametric,
                                       stochastic, xla_backend, chunk,
                                       enable_fast, has_burn, has_send)
     jax.block_until_ready(res)
-    keep = (np.ones(n_lanes, bool) if valid is None
-            else np.asarray(valid, bool))
     shards = 1 if mesh is None else int(mesh.devices.size)
-    counts = _pop_event_counts(res, keep, chunk, shards, chunk_index)
+    gid = (np.zeros(n_lanes, np.int64) if group_id is None
+           else np.asarray(group_id))
+    counts = _pop_event_counts(res, keep, chunk, shards, chunk_index,
+                               gid[keep], n_groups)
     out = _lane_results(res, n_lanes, trace_cum, tail_s, has_send,
                         chunk_index=chunk_index)
     if reduce == "stats":
-        gid = (np.zeros(n_lanes, np.int64) if group_id is None
-               else np.asarray(group_id))
         with TraceAnnotation("fleet.fold", chunk=chunk_index):
             stats = stats_from_outputs({k: v[keep] for k, v in out.items()},
                                        edges, gid[keep], n_groups)
-        stats.replay_events, stats.replay_event_slots = counts
+        _set_event_counts(stats, *counts)
         return stats
     return out
 
@@ -1640,24 +1668,35 @@ def _lane_results(res: dict, m: int, trace_cum, tail_s,
 
 
 def _pop_event_counts(res: dict, keep: np.ndarray, chunk: int, shards: int,
-                      chunk_index: int) -> tuple[int, int]:
+                      chunk_index: int, group_id: np.ndarray,
+                      n_groups: int) -> tuple[np.ndarray, int]:
     """Take the event replay's per-lane ``events`` counter off a replay
     result, so that only the lane channels go on to the host: returns the
     lane-events of the ``keep`` lanes (the first ``len(keep)`` of the
-    batch, masked) and the lane-event slots the whole batch ran
-    (:func:`repro.kernels.charge_replay.event_slots`); ``(0, 0)`` where
+    batch, masked), folded by their ``group_id`` into an int64
+    ``(n_groups,)`` vector, and the lane-event slots the whole batch ran
+    (:func:`repro.kernels.charge_replay.event_slots`); zeros and 0 where
     the replay has no event loop."""
     events = res.pop("events", None)
     if events is None:
-        return 0, 0
+        return np.zeros(n_groups, np.int64), 0
     from jax.profiler import TraceAnnotation
 
     from repro.kernels.charge_replay import event_slots
 
     with TraceAnnotation("fleet.download", chunk=chunk_index):
         events = np.asarray(events)
-    return (int(events[:keep.shape[0]][keep].sum()),
-            event_slots(events, chunk, shards))
+    # float64 weights hold each group's sum exactly (below 2**53)
+    per_group = np.bincount(group_id, weights=events[:keep.shape[0]][keep],
+                            minlength=n_groups).astype(np.int64)
+    return per_group, event_slots(events, chunk, shards)
+
+
+def _set_event_counts(stats, group_events: np.ndarray, slots: int) -> None:
+    """Book one replay call's event counts on its ``FleetStats``."""
+    stats.group_events = group_events
+    stats.replay_events = int(group_events.sum())
+    stats.replay_event_slots = slots
 
 
 def _lane_io_bytes(n_lanes: int, *arrays) -> int:
@@ -1717,7 +1756,10 @@ def _chunked_replay(plan_rows: dict, n_rows, n_lanes: int,
     Each stage runs in a ``jax.profiler`` span tagged with its chunk
     index, inside one ``fleet.sweep`` span: ``fleet.sample``,
     ``fleet.prep``, ``fleet.queue_wait``, ``fleet.dispatch``,
-    ``fleet.download`` and ``fleet.fold``."""
+    ``fleet.download`` and ``fleet.fold``; ``fleet.rows`` times each
+    conversion and upload of a row table.  ``fleet.sweep`` and
+    ``fleet.dispatch`` count their candidate plans (``plans``): the
+    stacked set's, and those among a chunk's real lanes."""
     from jax.profiler import TraceAnnotation
 
     if lane_chunk < 1:
@@ -1813,7 +1855,10 @@ def _chunked_replay(plan_rows: dict, n_rows, n_lanes: int,
             plan_idx=c["pidx"], conf=c["conf"], radio=radio,
             config_out=config_out, chunk_index=c["i"])
 
-    with TraceAnnotation("fleet.sweep", lanes=n_lanes, chunks=len(starts)):
+    n_plans = (int(np.shape(n_rows)[0]) if plan_mode
+               else n_lanes if per_lane_rows else 1)
+    with TraceAnnotation("fleet.sweep", lanes=n_lanes, chunks=len(starts),
+                         plans=n_plans):
         if prefetch and len(starts) > 1:
             return _overlapped_replay(
                 plan_rows, n_rows, lane_chunk, starts, build, chunk_bytes,
@@ -1926,11 +1971,9 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
 
         def rows_on_device(rows_c, state):
             if per_lane_rows:
-                return {k: jnp.asarray(v)
-                        for k, v in _rows_as(rows_c, state).items()}
+                return _upload_rows(rows_c, state)
             if state not in jrows:
-                jrows[state] = {k: jnp.asarray(v) for k, v in
-                                _rows_as(rows_h, state).items()}
+                jrows[state] = _upload_rows(rows_h, state)
             return jrows[state]
 
         with _x64():
@@ -1985,7 +2028,9 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
         def dispatch(item, ec):  # noqa: F811
             c, enable_fast, args, _, _, state = item
             with TraceAnnotation("fleet.dispatch", chunk=c["i"],
-                                 state=state.name):
+                                 state=state.name,
+                                 plans=_distinct_plans(shared_rows, c["pidx"],
+                                                       c["valid"])):
                 return _jit_replay(shared_rows, adaptive, parametric,
                                    stochastic, xla_backend, ec,
                                    enable_fast, has_burn, has_send)(*args)
@@ -2039,14 +2084,15 @@ def _overlapped_replay(plan_rows: dict, n_rows, lane_chunk: int,
     def finish(item, res):
         c, _, _, cum, tail, _ = item
         jax.block_until_ready(res)
-        counts = _pop_event_counts(res, c["valid"], echunk, 1, c["i"])
+        counts = _pop_event_counts(res, c["valid"], echunk, 1, c["i"],
+                                   c["gid"][:c["m"]], n_groups)
         out = _lane_results(res, c["m"], cum, tail, has_send,
                             chunk_index=c["i"])
         if stats:
             with TraceAnnotation("fleet.fold", chunk=c["i"]):
                 out = stats_from_outputs(out, edges, c["gid"][:c["m"]],
                                          n_groups)
-            out.replay_events, out.replay_event_slots = counts
+            _set_event_counts(out, *counts)
         keep(c, out)
 
     def next_chunk(i):

@@ -176,6 +176,13 @@ class FleetStats:
     # paths that have no event loop
     replay_events: int = 0
     replay_event_slots: int = 0
+    # the active lane-events of each group's lanes, (G,) int64: summed,
+    # ``replay_events``
+    group_events: np.ndarray | None = None
+
+    def __post_init__(self):
+        if self.group_events is None:
+            self.group_events = np.zeros(self.count.shape[0], np.int64)
 
     # -- associative accumulation ----------------------------------------
     def merge(self, other: "FleetStats") -> "FleetStats":
@@ -206,7 +213,8 @@ class FleetStats:
                                 other.peak_lane_bytes),
             replay_events=self.replay_events + other.replay_events,
             replay_event_slots=(self.replay_event_slots
-                                + other.replay_event_slots))
+                                + other.replay_event_slots),
+            group_events=self.group_events + other.group_events)
 
     # -- queries ---------------------------------------------------------
     @property
@@ -295,6 +303,7 @@ class FleetStats:
             "peak_lane_bytes": int(self.peak_lane_bytes),
             "replay_events": int(self.replay_events),
             "replay_event_slots": int(self.replay_event_slots),
+            "group_events": int(self.group_events[g]),
         }
 
 

@@ -7,7 +7,8 @@ replay programs.
 
 runs one profiled sweep and prints its spans as one JSON line: the test
 of the four-device mesh runs so, in a process with four virtual CPU
-devices.
+devices.  The design sweep of a ``PlanSet`` carries its candidates in the
+spans' ``plans`` args.
 """
 
 from __future__ import annotations
@@ -23,8 +24,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from repro.core import (Conv2D, DenseFC, MaxPool2D, SimNet, build_plan,
-                        fleet_sweep)
+from repro.core import (Conv2D, DenseFC, MaxPool2D, PlanSet, SimNet,
+                        build_plan, fleet_sweep)
 from repro.core import fleetsim
 from repro.kernels.charge_replay import event_slots
 
@@ -54,14 +55,19 @@ def profiled_spans(prefetch: int, shards: int = 0) -> list[dict]:
     """The ``fleet.*`` spans of one profiled two-chunk sweep (after an
     unprofiled one that compiles), each with its thread's line and its
     arguments."""
-    import jax
-
     mesh = None
     if shards:
         from repro.launch.mesh import make_fleet_mesh
         mesh = make_fleet_mesh(shards)
     plan = build_plan(*_net(), "sonic", "1mF")
-    kw = dict(SWEEP, plan=plan, prefetch=prefetch, mesh=mesh)
+    return _profile(dict(SWEEP, plan=plan, prefetch=prefetch, mesh=mesh))
+
+
+def _profile(kw: dict) -> list[dict]:
+    """The ``fleet.*`` spans of ``fleet_sweep(**kw)``, profiled after an
+    unprofiled call that compiles."""
+    import jax
+
     fleet_sweep(**kw)
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -90,16 +96,17 @@ def _check_spans(spans, queue: bool) -> int:
     """Every span of the table is there, for chunks 0 and 1; returns the
     line of the thread that ran the sweep."""
     [sweep] = [s for s in spans if s["name"] == "fleet.sweep"]
-    assert sweep["args"] == {"lanes": LANES, "chunks": 2}
+    assert sweep["args"] == {"lanes": LANES, "chunks": 2, "plans": 1}
     for name in STAGES:
         assert _chunks(spans, name) == {0, 1}, name
     assert _chunks(spans, "fleet.queue_wait") == ({1} if queue else set())
     names = {s["name"] for s in spans}
-    assert names == {"fleet.sweep", *STAGES} | (
+    assert names == {"fleet.sweep", "fleet.rows", *STAGES} | (
         {"fleet.queue_wait"} if queue else set())
-    # the fixed-policy stochastic sweep replays every chunk in int64
-    assert {s["args"].get("state") for s in spans
-            if s["name"] == "fleet.dispatch"} == {"int64"}
+    # the fixed-policy stochastic sweep replays every chunk in int64, one
+    # plan a chunk
+    assert {(s["args"].get("state"), s["args"].get("plans")) for s in spans
+            if s["name"] == "fleet.dispatch"} == {("int64", 1)}
     for s in spans:
         assert sweep["start"] <= s["start"] <= s["end"] <= sweep["end"] \
             or s["line"] != sweep["line"], s
@@ -140,6 +147,55 @@ def test_meshed_sweep_prepares_on_the_calling_thread():
     main = _check_spans(spans, queue=True)
     assert {s["line"] for s in spans if s["name"] == "fleet.prep"} == {main}
     assert _chunks(spans, "fleet.sample", main) == {0}
+
+
+def _table_bytes(plan) -> int:
+    """Bytes of the plan's bucketed row table in the integer state."""
+    rows = fleetsim._bucket_rows(fleetsim._plan_rows(plan), lane_axis=False)
+    return sum(v.nbytes for v in fleetsim._rows_as(
+        rows, np.dtype(np.int64)).values())
+
+
+def test_row_table_is_uploaded_once_per_state():
+    """The overlapped sweep converts and uploads the shared row table once
+    for the state its chunks replay in; the synchronous loop, once a
+    chunk."""
+    plan = build_plan(*_net(), "sonic", "1mF")
+    want = {"rows": fleetsim._bucket_target(len(plan)),
+            "bytes": _table_bytes(plan)}
+    for prefetch, chunks in ((1, 1), (0, 2)):
+        spans = profiled_spans(prefetch=prefetch)
+        rows = [s for s in spans if s["name"] == "fleet.rows"]
+        assert [s["args"] for s in rows] == [want] * chunks, prefetch
+        [prep] = [s for s in spans if s["name"] == "fleet.prep"
+                  and s["args"]["chunk"] == 0]
+        assert prep["start"] <= rows[0]["start"] <= rows[0]["end"] \
+            <= prep["end"]
+
+
+@pytest.mark.parametrize("prefetch", [0, 1])
+def test_design_sweep_spans_count_its_candidates(prefetch):
+    """A PlanSet of three candidates, plan-major lanes in chunks that
+    hold one or two of them: ``fleet.sweep`` carries the set's size,
+    each ``fleet.dispatch`` the candidates among its chunk's real lanes,
+    and ``fleet.rows`` the whole stacked table, once per state (once a
+    chunk on the synchronous loop)."""
+    net, x = _net()
+    ps = PlanSet.from_plans([build_plan(net, x, s, "1mF")
+                             for s in ("sonic", "tails", "tile-8")])
+    kw = dict(SWEEP, plan=ps, n_devices=12, lane_chunk=16,
+              prefetch=prefetch)
+    spans = _profile(kw)
+    [sweep] = [s for s in spans if s["name"] == "fleet.sweep"]
+    assert sweep["args"] == {"lanes": 36, "chunks": 3, "plans": 3}
+    # lanes 0-11, 12-23, 24-35: chunk 0 holds candidates 0 and 1, chunk 1
+    # candidates 1 and 2, chunk 2 four lanes of candidate 2 and padding
+    assert {s["args"]["chunk"]: s["args"]["plans"] for s in spans
+            if s["name"] == "fleet.dispatch"} == {0: 2, 1: 2, 2: 1}
+    rows = [s["args"] for s in spans if s["name"] == "fleet.rows"]
+    table = ps.rows["kind"].size
+    assert [r["rows"] for r in rows] == [table] * (1 if prefetch else 3)
+    assert all(r["bytes"] > 0 for r in rows)
 
 
 def test_event_slots_by_hand():
